@@ -25,6 +25,11 @@ shared on-disk artifact store — and records end-to-end queue latency
     compared field-by-field against a solo ``compile()`` (bit
     identity, the guarantee the store's content addressing makes).
 
+The farm phases run first and the solo phases after every farm process
+has exited: on the jax backend the process that first touches jax owns
+the host's TPU chips, so the parent stays off jax until its workers are
+gone.
+
 Acceptance (asserted in the full run AND recorded in the JSON):
 shared-warm fleet p50 is >=10x faster than the cold-solo p50; no
 tenant's p99 exceeds 3x the fleet p99 (fair-share admission under
@@ -33,7 +38,7 @@ mixed load); every farm schedule is bit-identical to solo.
 Usage:
     PYTHONPATH=src python benchmarks/farm_saturation.py \
         [--out BENCH_farm.json] [--smoke] [--requests N] \
-        [--workers N] [--backend numpy|jax|...]
+        [--workers N] [--backend numpy|jax|jax-pallas-interpret]
 
 ``--smoke`` is the CI guard: a small request count on 2 workers
 (numpy backend), asserting solo parity and a nonzero cross-process
@@ -60,6 +65,7 @@ except ImportError:  # direct script run: benchmarks/ is sys.path[0]
     from _host import host_meta
 
 from repro.core import OrchestratorConfig
+from repro.core.backend import configure_compile_cache
 from repro.models.edge_cnn import edge_network
 from repro.service import (
     CompileFarm,
@@ -163,8 +169,8 @@ def run_farm(root, trace: dict[str, list[Point]], *, workers: int,
     """One farm pass over the trace; returns (results-by-uid, the
     uid -> Point map, aggregate counters, drain wall)."""
     uid_to_point: dict[int, Point] = {}
-    with CompileFarm(root, n_workers=workers,
-                     batch_size=batch_size) as farm:
+    with CompileFarm(root, n_workers=workers, batch_size=batch_size,
+                     backend=backend) as farm:
         for tenant, pts in trace.items():
             uids = farm.submit(tenant,
                                [p.request(backend) for p in pts])
@@ -218,13 +224,21 @@ def cold_solo_phase(points: list[Point],
                                     in sorted(per_tenant.items())}}}
 
 
-def same_schedule(a, b) -> bool:
-    return (a is not None and b is not None
-            and a.rails == b.rails
-            and a.layer_voltages == b.layer_voltages
-            and a.e_total == b.e_total
-            and a.t_infer == b.t_infer
-            and a.feasible == b.feasible)
+_PARITY_FIELDS = ("rails", "layer_voltages", "e_total", "t_infer",
+                  "feasible")
+
+
+def schedule_diff(a, b) -> list[str]:
+    """The fields on which two schedules differ (empty: bit-identical)."""
+    if a is None or b is None:
+        return [] if a is b else [f"schedule {a!r} vs {b!r}"]
+    out = []
+    for field in _PARITY_FIELDS:
+        x, y = getattr(a, field), getattr(b, field)
+        if x != y:
+            out.append(field if field == "layer_voltages"
+                       else f"{field} {x!r} vs {y!r}")
+    return out
 
 
 def parity_phase(points: list[Point], results: dict, uid_to_point,
@@ -235,10 +249,14 @@ def parity_phase(points: list[Point], results: dict, uid_to_point,
     for uid, res in sorted(results.items()):
         first_result.setdefault(uid_to_point[uid].name, res)
     per_point = {}
+    diffs = {}
     for p in points:
-        per_point[p.name] = same_schedule(p.solo(backend),
-                                          first_result[p.name].value)
-    return {"per_point": per_point,
+        diff = schedule_diff(first_result[p.name].value, p.solo(backend))
+        per_point[p.name] = not diff
+        if diff:
+            diffs[p.name] = diff
+            print(f"[parity] {p.name}: farm vs solo: {'; '.join(diff)}")
+    return {"per_point": per_point, "diffs": diffs,
             "identical": all(per_point.values())}
 
 
@@ -259,12 +277,6 @@ def run(n_requests: int, workers: int, backend: str | None,
         "tenants": {t: len(pts) for t, pts in trace.items()},
         "batch_size": 32,
     }
-
-    print(f"[cold_solo] measuring {len(points)} distinct points ...")
-    results["cold_solo"] = cold_solo_phase(points, trace, backend)
-    p50_solo = results["cold_solo"]["latency"]["fleet"]["p50_s"]
-    print(f"[cold_solo] modeled serial p50 {p50_solo:.2f}s "
-          f"(serial wall {results['cold_solo']['serial_wall_s']:.1f}s)")
 
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="farm_bench_"))
     try:
@@ -293,9 +305,6 @@ def run(n_requests: int, workers: int, backend: str | None,
               f"p99 {warm_lat['fleet']['p99_s']:.2f}s  "
               f"disk_hits {warm_counters['disk_hits']}")
 
-        results["parity"] = parity_phase(points, warm_res, warm_map,
-                                         backend)
-
         if not smoke:
             scaling = []
             short = build_trace(points, max(200, n_requests // 5))
@@ -313,6 +322,14 @@ def run(n_requests: int, workers: int, backend: str | None,
             results["scaling"] = scaling
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
+
+    # every farm process has exited: the parent may touch jax now
+    print(f"[cold_solo] measuring {len(points)} distinct points ...")
+    results["cold_solo"] = cold_solo_phase(points, trace, backend)
+    p50_solo = results["cold_solo"]["latency"]["fleet"]["p50_s"]
+    print(f"[cold_solo] modeled serial p50 {p50_solo:.2f}s "
+          f"(serial wall {results['cold_solo']['serial_wall_s']:.1f}s)")
+    results["parity"] = parity_phase(points, warm_res, warm_map, backend)
 
     warm_p50 = warm_lat["fleet"]["p50_s"]
     results["acceptance"] = {
@@ -340,11 +357,11 @@ def main() -> None:
     ap.add_argument("--workers", type=int, default=2,
                     help="farm worker processes (default 2)")
     ap.add_argument("--backend", default=None,
-                    choices=("numpy", "jax", "jax-pallas",
-                             "jax-pallas-interpret"),
+                    choices=("numpy", "jax", "jax-pallas-interpret"),
                     help="solver array backend inside the workers "
                          "(default: $PFDNN_BACKEND or numpy)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     tic = time.perf_counter()
     n_requests = args.requests or (24 if args.smoke else 1000)

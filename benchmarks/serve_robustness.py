@@ -44,7 +44,7 @@ its snaps still resolve from the re-centered precompiled set).
 Usage:
     PYTHONPATH=src python benchmarks/serve_robustness.py \
         [--out BENCH_serve.json] [--smoke] \
-        [--backend numpy|jax|jax-pallas|jax-pallas-interpret] \
+        [--backend numpy|jax|jax-pallas-interpret] \
         [--frames N]
 """
 
@@ -62,6 +62,7 @@ except ImportError:  # direct script run: benchmarks/ is sys.path[0]
     from _host import host_meta
 
 from repro.core import OrchestratorConfig
+from repro.core.backend import configure_compile_cache
 from repro.hw.edge40nm import EDGE40NM_DEFAULT as ACC
 from repro.models.edge_cnn import edge_network
 from repro.perfmodel import characterize_network, plan_banks
@@ -285,13 +286,13 @@ def main() -> None:
                     help="short horizon; assert the acceptance block "
                          "and exit without writing the JSON")
     ap.add_argument("--backend", default=None,
-                    choices=("numpy", "jax", "jax-pallas",
-                             "jax-pallas-interpret"),
+                    choices=("numpy", "jax", "jax-pallas-interpret"),
                     help="solver array backend for the contingency "
                          "compile (default: $PFDNN_BACKEND or numpy)")
     ap.add_argument("--frames", type=int, default=None,
                     help="trace length (default 420; smoke 180)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     tic = time.perf_counter()
     n_frames = args.frames or (180 if args.smoke else 420)

@@ -28,7 +28,7 @@ schedules must equal the independent compiles.
 Usage:
     PYTHONPATH=src python benchmarks/service_speed.py \
         [--out BENCH_service.json] [--smoke] \
-        [--backend numpy|jax|jax-pallas|jax-pallas-interpret] \
+        [--backend numpy|jax|jax-pallas-interpret] \
         [--reps N]
 
 On the jax backends the ``cold_many_stacked`` / ``warm_solve`` rows
@@ -61,6 +61,7 @@ from repro.core import (
     ParetoFront,
     compile_power_schedule,
 )
+from repro.core.backend import configure_compile_cache
 from repro.models.edge_cnn import edge_network
 from repro.service import CompileRequest, CompileService
 
@@ -266,14 +267,15 @@ def main() -> None:
                     help="two-request fleet; assert identical feasible "
                          "schedules across all variants and exit")
     ap.add_argument("--backend", default=None,
-                    choices=("numpy", "jax", "jax-pallas",
-                             "jax-pallas-interpret"),
+                    choices=("numpy", "jax", "jax-pallas-interpret"),
                     help="solver array backend (default: $PFDNN_BACKEND "
-                         "or numpy); jax-pallas* run the fused Pallas "
-                         "DP kernels and record io_delta columns")
+                         "or numpy); jax-pallas-interpret runs the "
+                         "Pallas DP kernels in interpret mode; jax "
+                         "backends record io_delta columns")
     ap.add_argument("--reps", type=int, default=3,
                     help="best-of-N walls per variant")
     args = ap.parse_args()
+    configure_compile_cache()
 
     tic = time.perf_counter()
     fleet = SMOKE_FLEET if args.smoke else FLEET

@@ -7,7 +7,7 @@ PRs have a perf trajectory.
 Usage:
     PYTHONPATH=src python benchmarks/sweep_speed.py \
         [--out BENCH_sweep.json] [--record-baseline] [--smoke] \
-        [--backend numpy|jax|jax-pallas|jax-pallas-interpret] \
+        [--backend numpy|jax|jax-pallas-interpret] \
         [--workers N] [--profile [DIR]]
 
 ``--record-baseline`` writes ``benchmarks/baseline_sweep.json`` instead
@@ -55,6 +55,8 @@ try:
 except ImportError:  # direct script run: benchmarks/ is sys.path[0]
     from common import max_rate, schedule_for, timed
     from _host import host_meta
+
+from repro.core.backend import configure_compile_cache
 
 HERE = pathlib.Path(__file__).parent
 BASELINE_PATH = HERE / "baseline_sweep.json"
@@ -163,8 +165,7 @@ def compare(results: dict[str, dict], reference: dict[str, dict],
 
 def bench_backends() -> list[str]:
     """Backends the bench can exercise here: the registry's names plus
-    the Pallas interpret mode whenever jax is importable (device mode
-    needs an accelerator, so it stays opt-in via ``--backend``)."""
+    the Pallas interpret mode whenever jax is importable."""
     from repro.core.backend import available_backends
 
     names = list(available_backends())
@@ -233,12 +234,11 @@ def main() -> None:
                     help="one small config; assert the sweep emits a "
                          "feasible schedule and exit (CI guard)")
     ap.add_argument("--backend", default=None,
-                    choices=("numpy", "jax", "jax-pallas",
-                             "jax-pallas-interpret"),
+                    choices=("numpy", "jax", "jax-pallas-interpret"),
                     help="solver array backend (default: $PFDNN_BACKEND "
-                         "or numpy); the jax-pallas* names run the "
-                         "fused Pallas DP kernels (device columns "
-                         "h2d_lane_uploads/h2d_lane_bytes/"
+                         "or numpy); jax-pallas-interpret runs the "
+                         "fused Pallas DP kernels in interpret mode "
+                         "(device columns h2d_lane_uploads/h2d_lane_bytes/"
                          "kernel_dispatches are recorded per row)")
     ap.add_argument("--workers", type=int, default=None,
                     help="rail-sweep thread fan-out (default: "
@@ -251,6 +251,7 @@ def main() -> None:
                          "compile to DIR (default benchmarks/trace) "
                          "and exit; requires a jax backend")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.profile is not None:
         if args.backend == "numpy":

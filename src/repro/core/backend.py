@@ -17,8 +17,9 @@ or on ``jax.numpy`` with ``jit`` when jax is installed:
     programs over *padded* per-layer tensors.  State counts are padded
     to a power-of-two bucket so rail subsets of the same master table
     reuse one compilation instead of tracing per subset; float64 is
-    enforced per-call via ``jax.experimental.enable_x64`` so the global
-    x64 flag (and the rest of the repo's float32 jax code) is untouched.
+    enforced per-call by the ``jax.enable_x64(True)`` context manager so
+    the global x64 flag (and the rest of the repo's float32 jax code) is
+    untouched.
 
 Every kernel also has a **subset-stacked** variant that takes a
 :class:`StackedArrays` — the padded tensors of B same-bucket rail
@@ -37,22 +38,26 @@ Backend selection: ``get_backend(None)`` honours the ``PFDNN_BACKEND``
 environment variable (``numpy`` | ``jax``), defaulting to numpy, so the
 jax path stays strictly opt-in.
 
-``PFDNN_PALLAS`` layers the fused Pallas kernels of
-``repro.kernels.dp_sweep`` on top of the jax backend:  ``interpret``
-runs them in interpret mode (CPU-safe — the tier-1 correctness mode),
-``1`` / ``device`` compiles them for the accelerator.  The same modes
-are reachable as explicit backend names ``jax-pallas-interpret`` /
-``jax-pallas`` and per-compile via ``OrchestratorConfig.pallas``.
-Kernel results are bit-identical to the scan path in every mode (the
-tests pin this across all goldens).
+``PFDNN_PALLAS=interpret`` layers the fused Pallas kernels of
+``repro.kernels.dp_sweep`` on top of the jax backend in interpret mode
+(CPU-safe — the kernels' correctness vehicle); the same mode is the
+backend name ``jax-pallas-interpret`` and ``OrchestratorConfig(pallas=
+"interpret")``.  Kernel results are bit-identical to the scan path (the
+tests pin this across all goldens).  The device mode (``PFDNN_PALLAS=
+1|on|device|true``, the name ``jax-pallas``, ``pallas="device"``) is
+refused with :class:`PallasDeviceUnsupported`: the TPU compiler rejects
+these kernels, so on the chip the stacked sweep runs as the plain jax
+backend's ``lax.scan`` programs.
 
 The jax backend is also **device-resident**: every :class:`BucketStack`
 gets a device mirror of its lane tensors, synced incrementally — each
 lane is uploaded ONCE when first seen, capacity growth copies on
 device, and the lane-indexed kernel entry points (``dp_multi_lanes``,
-``kbest_multi_lanes``, ``path_costs_lanes``) gather their operands from
-the mirror, so warm sweep rounds perform zero host→device operand
-transfers and only argmin indices / cost scalars come back.  The lanes
+``kbest_multi_lanes``) gather their operands from the mirror, so warm
+sweep rounds perform zero host→device operand transfers and only argmin
+indices come back; ``path_costs_lanes`` prices the chosen paths from
+the store's host tensors (the device's float64 values are not numpy's
+to the last bit — :meth:`JaxBackend.path_costs`).  The lanes
 API returns :class:`PendingResult` handles on request (``defer=True``)
 so the round scheduler can dispatch every group of a round before
 blocking on any result (jax async dispatch overlaps the rest);
@@ -76,6 +81,8 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
+import sys
 import threading
 
 from repro.analysis.lockcheck import make_lock
@@ -96,6 +103,22 @@ _PALLAS_MODES = {
 # name="jax" plus the matching PFDNN_PALLAS value)
 _PALLAS_NAMES = {"jax-pallas": "device",
                  "jax-pallas-interpret": "interpret"}
+
+
+class PallasDeviceUnsupported(ValueError):
+    """Raised wherever the Pallas *device* mode is requested."""
+
+    def __init__(self, how: str):
+        super().__init__(
+            f"{how}: the Pallas device mode is refused. The TPU compiler "
+            "rejects the repro.kernels.dp_sweep kernels: they run in "
+            "float64 ('Only float32 is supported'), the DP gathers with "
+            "a 3-D take_along_axis ('Only 2D gather is supported'), the "
+            "k-best frontier scatters into its cost slab, and the per-lane "
+            "(1, K) weight blocks break the (8, 128) block alignment. Run "
+            "the chip on backend='jax' (the lax.scan programs), or use "
+            "pallas='interpret' to test the kernels on the CPU. Moving the "
+            "kernels to float32 is ROADMAP speed item 4.")
 
 
 def _pallas_mode_from_env() -> str | None:
@@ -875,14 +898,15 @@ class JaxBackend:
     """jax.numpy + jit backend: the same kernels as ``lax.scan``
     programs, compiled once per (L, S bucket, K) shape.
 
-    ``pallas`` routes the stacked kernels through the fused Pallas
-    programs of ``repro.kernels.dp_sweep`` instead of the scan path:
-    ``"interpret"`` runs them in interpret mode (CPU-safe, bit-identical
-    — the tier-1 correctness mode), ``"device"`` compiles them for the
-    accelerator.  Non-stacked entry points keep their existing routing
-    either way — the sweep engine only ever issues stacked calls on its
-    hot path, and interpret-mode execution of the cold scalar probes
-    would dominate the CPU suite for no coverage gain.
+    ``pallas="interpret"`` routes the stacked kernels through the fused
+    Pallas programs of ``repro.kernels.dp_sweep`` in interpret mode
+    (CPU-safe, bit-identical — the kernels' correctness vehicle) instead
+    of the scan path; ``"device"`` raises
+    :class:`PallasDeviceUnsupported`.  Non-stacked entry points keep
+    their existing routing either way — the sweep engine only ever
+    issues stacked calls on its hot path, and interpret-mode execution
+    of the cold scalar probes would dominate the CPU suite for no
+    coverage gain.
     """
 
     name = "jax"
@@ -894,17 +918,15 @@ class JaxBackend:
     def __init__(self, pallas: str | None = None) -> None:
         import jax  # noqa: F401 — fail loudly at construction
 
-        if pallas not in (None, "interpret", "device"):
+        if pallas == "device":
+            raise PallasDeviceUnsupported("JaxBackend(pallas='device')")
+        if pallas not in (None, "interpret"):
             raise ValueError(
-                f"pallas={pallas!r}: expected None, 'interpret' or "
-                "'device'")
+                f"pallas={pallas!r}: expected None or 'interpret'")
         self.pallas_mode = pallas
-        self._interpret = pallas == "interpret"
         self._jax = jax
         self._dp = jax.jit(self._dp_impl)
         self._dp_stacked = jax.jit(jax.vmap(self._dp_impl))
-        self._costs = jax.jit(self._costs_impl)
-        self._costs_stacked = jax.jit(self._costs_stacked_impl)
         # k is a static shape parameter of the k-best scan — one
         # compiled program per (k, stacked?) requested
         self._kbest_jits: dict[tuple[int, bool], object] = {}
@@ -935,7 +957,7 @@ class JaxBackend:
     # backtracking and the DP share one compiled program; float64 is
     # scoped to the call so the repo's float32 jax code is unaffected.
     def _x64(self):
-        return self._jax.experimental.enable_x64()
+        return self._jax.enable_x64(True)
 
     _DP_NAMES = ("t_op", "e_op", "valid", "t_trans", "e_trans")
     _COST_NAMES = ("t_op", "e_op", "t_trans", "e_trans", "switch")
@@ -989,12 +1011,35 @@ class JaxBackend:
         _, states = lax.scan(back, s_final, parents, reverse=True)
         return jnp.concatenate([states, s_final[None, :]], axis=0).T
 
+    def _smallest_k(self, x, k: int):
+        """Indices of the ``k`` smallest entries of ``x`` along axis 1,
+        in stable ``(value, index)`` order — the first ``k`` columns of
+        numpy's ``argsort(kind="stable")``, ties (``inf`` included) in
+        index order.  ``k`` rounds of min + first-hit selection instead
+        of a full sort: the TPU compiler spends most of a k-best
+        program's compile time on a float64 sort (a v5e compile of the
+        S_pad=64 program took ~40 s with the sort and ~10 s with this,
+        on a CPU host), while these reductions compile in seconds."""
+        jnp = self._jax.numpy
+        n = x.shape[1]
+        pos = jnp.arange(n).reshape((1, n) + (1,) * (x.ndim - 2))
+        taken = jnp.zeros(x.shape, dtype=bool)
+        picks = []
+        for _ in range(k):
+            low = jnp.where(taken, jnp.inf, x).min(axis=1, keepdims=True)
+            # first untaken entry equal to the minimum — when only inf
+            # is left, the lowest-index untaken inf
+            pick = jnp.argmax((x == low) & ~taken, axis=1)
+            picks.append(pick)
+            taken = taken | (pos == jnp.expand_dims(pick, 1))
+        return jnp.stack(picks, axis=1)
+
     def _kbest_impl(self, t_op, e_op, valid, t_trans, e_trans, mus, *,
                     k: int):
         """Single-problem multi-μ k-best frontier as a ``lax.scan``
         program — the jax twin of the numpy stacked kernel's per-lane
-        operations (``jnp.argsort`` is stable, matching numpy's
-        ``kind="stable"`` tie order exactly)."""
+        operations (:meth:`_smallest_k` selects in numpy's stable
+        ``(value, index)`` tie order exactly)."""
         jnp = self._jax.numpy
         lax = self._jax.lax
         L, S = t_op.shape
@@ -1010,7 +1055,7 @@ class JaxBackend:
             edge = et[None, :, :] + mu3 * tt[None, :, :]     # [K, Sp, Sn]
             cand = (costs[:, :, :, None]
                     + edge[:, :, None, :]).reshape(K, S * k, S)
-            order = jnp.argsort(cand, axis=1)[:, :k, :]      # stable
+            order = self._smallest_k(cand, k)               # [K, k, S]
             vals = jnp.take_along_axis(cand, order, axis=1)
             new_costs = vals.transpose(0, 2, 1) + nd[:, :, None]
             return new_costs, (order // k, order % k)
@@ -1018,7 +1063,7 @@ class JaxBackend:
         costs, (ps, pr) = lax.scan(step, costs0,
                                    (t_trans, e_trans, node[1:]))
         flat = costs.reshape(K, S * k)
-        order = jnp.argsort(flat, axis=1)[:, :k]             # [K, k]
+        order = self._smallest_k(flat, k)                    # [K, k]
         counts = jnp.minimum(k, jnp.isfinite(flat).sum(axis=1))
         s, r = order // k, order % k
         qi = jnp.arange(K)[:, None]
@@ -1047,23 +1092,6 @@ class JaxBackend:
             self._kbest_jits[key] = jax.jit(fn)
         return self._kbest_jits[key]
 
-    def _costs_impl(self, t_op, e_op, t_trans, e_trans, switch, paths):
-        jnp = self._jax.numpy
-        L = t_op.shape[0]
-        li = jnp.arange(L)[None, :]
-        t_sum = t_op[li, paths].sum(axis=1)
-        e_sum = e_op[li, paths].sum(axis=1)
-        if L == 1:
-            zero = jnp.zeros_like(t_sum)
-            return (t_sum, e_sum, zero, zero,
-                    jnp.zeros(t_sum.shape, dtype=jnp.int64))
-        lt = jnp.arange(L - 1)[None, :]
-        a, b = paths[:, :-1], paths[:, 1:]
-        return (t_sum, e_sum,
-                t_trans[lt, a, b].sum(axis=1),
-                e_trans[lt, a, b].sum(axis=1),
-                switch[lt, a, b].sum(axis=1))
-
     # minimum DP slab size (weights × layers × S²) worth a jitted
     # dispatch on a CPU host; smaller slabs (envelope probes, short
     # rounds) run on the numpy kernel, whose paths are identical.  The
@@ -1087,20 +1115,16 @@ class JaxBackend:
                 jnp.asarray(np.asarray(w_t, dtype=float)))
             return np.asarray(paths, dtype=np.int64)
 
+    # Path costs are the schedule's ledger, and the numeric contract is
+    # numpy's float64: on a TPU, costs gathered from the device and even
+    # summed on the host moved e_total / t_infer by a few ulps on 3 of
+    # the 23 goldens.  So the device only picks paths (DP, k-best) and
+    # every path cost is gathered and summed on the host, from the host
+    # copy of the same tensors.
+
     def path_costs(self, problem, paths: np.ndarray
                    ) -> dict[str, np.ndarray]:
-        if self._cpu:       # gather-bound: jit cannot win on a CPU host
-            return self._host.path_costs(problem, paths)
-        jnp = self._jax.numpy
-        padded = problem.padded_arrays()
-        dev = self._dev(padded, self._COST_NAMES)
-        with self._x64():
-            t_op, e_op, t_trans, e_trans, n_switch = self._costs(
-                *dev, jnp.asarray(paths))
-        return {"t_op": np.asarray(t_op), "e_op": np.asarray(e_op),
-                "t_trans": np.asarray(t_trans),
-                "e_trans": np.asarray(e_trans),
-                "n_switch": np.asarray(n_switch, dtype=np.int64)}
+        return self._host.path_costs(problem, paths)
 
     # -- stacked variants ---------------------------------------------
     # Lane counts are padded to a power-of-two bucket (repeating lane 0)
@@ -1183,7 +1207,7 @@ class JaxBackend:
                 from repro.kernels.dp_sweep import dp_multi_stacked_pallas
                 paths = dp_multi_stacked_pallas(
                     *dev, jnp.asarray(w), jnp.asarray(t),
-                    interpret=self._interpret)
+                    interpret=True)
             else:
                 paths = self._dp_stacked(
                     *dev, jnp.asarray(w), jnp.asarray(t))
@@ -1223,41 +1247,28 @@ class JaxBackend:
                     kbest_multi_stacked_pallas)
                 paths, counts = kbest_multi_stacked_pallas(
                     *dev, jnp.asarray(m), k=k,
-                    interpret=self._interpret)
+                    interpret=True)
             else:
                 paths, counts = self._kbest_fn(k, stacked=True)(
                     *dev, jnp.asarray(m))
             return (np.asarray(paths, dtype=np.int64)[:B, :K],
                     np.asarray(counts, dtype=np.int64)[:B, :K])
 
-    def _costs_stacked_impl(self, t_op, e_op, t_trans, e_trans, switch,
-                            lanes, paths):
-        jnp = self._jax.numpy
-        L = t_op.shape[1]
-        ln = lanes[:, None]
-        li = jnp.arange(L)[None, :]
-        t_sum = t_op[ln, li, paths].sum(axis=1)
-        e_sum = e_op[ln, li, paths].sum(axis=1)
-        if L == 1:
-            zero = jnp.zeros_like(t_sum)
-            return (t_sum, e_sum, zero, zero,
-                    jnp.zeros(t_sum.shape, dtype=jnp.int64))
-        lt = jnp.arange(L - 1)[None, :]
-        a, b = paths[:, :-1], paths[:, 1:]
-        return (t_sum, e_sum,
-                t_trans[ln, lt, a, b].sum(axis=1),
-                e_trans[ln, lt, a, b].sum(axis=1),
-                switch[ln, lt, a, b].sum(axis=1))
+    @staticmethod
+    def _host_sums(comps) -> dict[str, np.ndarray]:
+        """numpy sums of per-layer path components gathered by the
+        Pallas kernel — the exact summation of the numpy backend."""
+        t, e, tt, et, sw = (np.asarray(c) for c in comps)
+        return {"t_op": t.sum(axis=1), "e_op": e.sum(axis=1),
+                "t_trans": tt.sum(axis=1), "e_trans": et.sum(axis=1),
+                "n_switch": sw.sum(axis=1).astype(np.int64)}
 
     def path_costs_stacked(self, stacked: StackedArrays,
                            lanes: np.ndarray, paths: np.ndarray
                            ) -> dict[str, np.ndarray]:
         if self.pallas_mode is not None and stacked.n_layers > 1:
-            # Pallas gather kernel returns PER-LAYER components; the
-            # sums happen here on the host with np.sum so they are
-            # bit-identical to the numpy backend's pairwise summation.
-            # (L == 1 has no transition components to gather — it falls
-            # through to the equivalent non-kernel paths below.)
+            # (L == 1 has no transition components for the kernel to
+            # gather — it takes the host path below)
             jnp = self._jax.numpy
             stacked, _ = self._pad_lanes(stacked)
             lanes_p, P = self._pad_rows(
@@ -1269,37 +1280,19 @@ class JaxBackend:
             with self._x64():
                 comps = path_components_pallas(
                     jnp.asarray(lanes_p), jnp.asarray(paths_p), *dev,
-                    interpret=self._interpret)
-            t, e, tt, et, sw = (np.asarray(c)[:P] for c in comps)
-            return {"t_op": t.sum(axis=1), "e_op": e.sum(axis=1),
-                    "t_trans": tt.sum(axis=1),
-                    "e_trans": et.sum(axis=1),
-                    "n_switch": sw.sum(axis=1).astype(np.int64)}
-        if self._cpu:       # gather-bound: jit cannot win on a CPU host
-            return self._host.path_costs_stacked(stacked, lanes, paths)
-        jnp = self._jax.numpy
-        stacked, _ = self._pad_lanes(stacked)
-        lanes = np.asarray(lanes, dtype=np.int64)
-        paths = np.asarray(paths, dtype=np.int64)
-        lanes_p, P = self._pad_rows(lanes)
-        paths_p, _ = self._pad_rows(paths)
-        dev = self._dev(stacked, self._COST_NAMES)
-        with self._x64():
-            t_op, e_op, t_trans, e_trans, n_switch = self._costs_stacked(
-                *dev, jnp.asarray(lanes_p), jnp.asarray(paths_p))
-        return {"t_op": np.asarray(t_op)[:P],
-                "e_op": np.asarray(e_op)[:P],
-                "t_trans": np.asarray(t_trans)[:P],
-                "e_trans": np.asarray(e_trans)[:P],
-                "n_switch": np.asarray(n_switch, dtype=np.int64)[:P]}
+                    interpret=True)
+            return self._host_sums(np.asarray(c)[:P] for c in comps)
+        return self._host.path_costs_stacked(stacked, lanes, paths)
 
     # -- device-resident lane path ------------------------------------
     # The round scheduler registers every live task's padded tensors as
     # lanes of a per-bucket BucketStack; these entry points read the
     # operands from the stack's device mirror instead of a per-round
     # host member stack, so warm rounds upload nothing — only the small
-    # weight/μ rows go down and only index/scalar results come back.
+    # weight/μ rows go down and only indices come back.
 
+    # the DP / k-best operands; the switch tensor follows them only for
+    # the Pallas cost gather (the scan path prices paths on the host)
     _LANE_NAMES = ("_t_op", "_e_op", "_valid", "_t_trans", "_e_trans",
                    "_switch")
 
@@ -1319,7 +1312,9 @@ class JaxBackend:
         bookkeeping check.  The mirror lives in the store's scratch
         dict, so dropping the stack (``ArtifactStore.clear`` /
         ``trim_stacks``) frees the device buffers with it."""
-        key = ("jax_lanes",)
+        names = self._LANE_NAMES if self.pallas_mode is not None \
+            else self._LANE_NAMES[:5]
+        key = ("jax_lanes", len(names))
         with store._lock:
             m = store.scratch.get(key)
             if m is None:
@@ -1328,7 +1323,7 @@ class JaxBackend:
             if m.n == store.n and m.cap == cap:
                 return m
             jnp = self._jax.numpy
-            host = [getattr(store, nm) for nm in self._LANE_NAMES]
+            host = [getattr(store, nm) for nm in names]
             with self._x64():
                 if m.cap != cap:
                     old = m.arrays or (None,) * len(host)
@@ -1396,7 +1391,6 @@ class JaxBackend:
             return fn
         jax = self._jax
         pallas = self.pallas_mode is not None
-        interp = self._interpret
         if kind == "dp":
             if pallas:
                 from repro.kernels.dp_sweep import dp_multi_stacked_pallas
@@ -1404,7 +1398,7 @@ class JaxBackend:
                 def impl(t_op, e_op, valid, tt, et, idx, w_e, w_t):
                     return dp_multi_stacked_pallas(
                         t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], w_e, w_t, interpret=interp)
+                        et[idx], w_e, w_t, interpret=True)
             else:
                 def impl(t_op, e_op, valid, tt, et, idx, w_e, w_t):
                     return jax.vmap(self._dp_impl)(
@@ -1418,25 +1412,20 @@ class JaxBackend:
                 def impl(t_op, e_op, valid, tt, et, idx, mus):
                     return kbest_multi_stacked_pallas(
                         t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], mus, k=k, interpret=interp)
+                        et[idx], mus, k=k, interpret=True)
             else:
                 def impl(t_op, e_op, valid, tt, et, idx, mus):
                     return jax.vmap(
                         lambda *a: self._kbest_impl(*a, k=k))(
                         t_op[idx], e_op[idx], valid[idx], tt[idx],
                         et[idx], mus)
-        elif kind == "costs":
-            if pallas:
-                from repro.kernels.dp_sweep import path_components_pallas
+        elif kind == "costs" and pallas:
+            # (the scan path's path costs are the host's — path_costs)
+            from repro.kernels.dp_sweep import path_components_pallas
 
-                def impl(t_op, e_op, tt, et, sw, lanes, paths):
-                    return path_components_pallas(
-                        lanes, paths, t_op, e_op, tt, et, sw,
-                        interpret=interp)
-            else:
-                # lane indices address the mirror directly — the
-                # existing stacked gather program needs no idx step
-                impl = self._costs_stacked_impl
+            def impl(t_op, e_op, tt, et, sw, lanes, paths):
+                return path_components_pallas(
+                    lanes, paths, t_op, e_op, tt, et, sw, interpret=True)
         else:
             raise ValueError(f"unknown lanes kernel {kind!r}")
         fn = jax.jit(impl)
@@ -1517,13 +1506,14 @@ class JaxBackend:
         """Summed cost components of paths on resident lanes (see
         :meth:`dp_multi_lanes` for the defer contract).  Lane indices
         are global stack slots, exactly as in ``path_costs_stacked`` on
-        ``store.view()``."""
+        ``store.view()``.  Only the Pallas kernel gathers on the device;
+        the scan path gathers from the store's host tensors (see
+        :meth:`path_costs`)."""
         lanes = np.asarray(lanes, dtype=np.int64)
         paths = np.asarray(paths, dtype=np.int64)
         L = store._t_op.shape[1]
-        if L == 1 or (self.pallas_mode is None and self._cpu):
-            # gather-bound on a CPU host; and L == 1 has no transition
-            # components for a kernel to gather
+        if L == 1 or self.pallas_mode is None:
+            # (L == 1 has no transition components for the kernel)
             out = self._host.path_costs_stacked(store.view(), lanes,
                                                 paths)
             return PendingResult.ready(out) if defer else out
@@ -1538,26 +1528,82 @@ class JaxBackend:
             dev = fn(*cost_arrs, jnp.asarray(lanes_p),
                      jnp.asarray(paths_p))
         self.io_stats["kernel_dispatches"] += 1
-        if self.pallas_mode is not None:
-            def collect():
-                # host-side np.sum over the gathered components — the
-                # exact summation of the numpy backend
-                t, e, tt, et, sw = (np.asarray(c)[:P] for c in dev)
-                return {"t_op": t.sum(axis=1), "e_op": e.sum(axis=1),
-                        "t_trans": tt.sum(axis=1),
-                        "e_trans": et.sum(axis=1),
-                        "n_switch": sw.sum(axis=1).astype(np.int64)}
-        else:
-            def collect():
-                t, e, tt, et, sw = dev
-                return {"t_op": np.asarray(t)[:P],
-                        "e_op": np.asarray(e)[:P],
-                        "t_trans": np.asarray(tt)[:P],
-                        "e_trans": np.asarray(et)[:P],
-                        "n_switch": np.asarray(sw,
-                                               dtype=np.int64)[:P]}
-        pend = PendingResult(collect)
+        pend = PendingResult(
+            lambda: self._host_sums(np.asarray(c)[:P] for c in dev))
         return pend if defer else pend.get()
+
+
+# ------------------------------------------------- process placement
+
+# the compile cache's place when $JAX_COMPILATION_CACHE_DIR is unset: a
+# fixed directory of the checkout (src/repro/core/ → the repo root), so
+# every run of the checkout finds the programs an earlier run compiled
+_CACHE_VAR = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT_CACHE = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+# JAX caches only programs that took at least this long to compile (1 s
+# by default).  Most sweep programs compile in ~0.1 s on a v5e host, so
+# the default kept 47 of the 374 programs of one chip_smoke.py run and
+# left ~70 s of recompiles to every later run; cache them all.
+_MIN_COMPILE_VAR = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    the worker processes it spawns; returns the cache directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when it is set, and no other
+    path is set; otherwise the cache lives in the checkout's
+    ``.jax_cache`` directory.  Both settings are exported through the
+    environment, which spawned workers read when they import jax.
+    Entry-point scripts call this; importing the library never does.
+    """
+    path = os.environ.setdefault(_CACHE_VAR, str(_CHECKOUT_CACHE))
+    min_s = float(os.environ.setdefault(_MIN_COMPILE_VAR, "0"))
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    return path
+
+
+def is_jax_backend(name: str | None) -> bool:
+    """Whether backend ``name`` (``None`` → ``$PFDNN_BACKEND`` or numpy)
+    runs on jax — decided from the name alone, so a caller can ask
+    without constructing the backend (which initialises jax)."""
+    if name is None:
+        name = os.environ.get(_ENV_VAR, _DEFAULT).strip().lower() \
+            or _DEFAULT
+    return name == "jax" or name in _PALLAS_NAMES
+
+
+def local_tpu_chips() -> int:
+    """TPU chips a jax process started here would open, or 0 when
+    ``$JAX_PLATFORMS`` keeps jax off the TPU: ``$TPU_VISIBLE_CHIPS``
+    when set, else the chip device nodes this process can see
+    (``/dev/accel*``, or the VFIO groups of newer TPUs — a host's PCI
+    bus may hold more chips than are passed through).  Counted without
+    initialising a jax backend, so a parent can ask before it spawns
+    the process that is to own the chips."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "").strip()
+    if visible:
+        return len(visible.split(","))
+    dev = pathlib.Path("/dev")
+    nodes = list(dev.glob("accel*")) or [
+        p for p in dev.glob("vfio/*") if p.name != "vfio"]
+    return len(nodes)
+
+
+def jax_backend_initialized() -> bool:
+    """Whether this process has initialised a jax backend — after that
+    it holds the host's TPU chips until it exits."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 # -------------------------------------------------------- registry
@@ -1595,6 +1641,10 @@ def get_backend(name: str | None = None):
         pallas = _PALLAS_NAMES[name]
     elif name == "jax":
         pallas = _pallas_mode_from_env()
+    if pallas == "device":
+        raise PallasDeviceUnsupported(
+            f"backend {name!r}" if name in _PALLAS_NAMES else
+            f"{_PALLAS_VAR}={os.environ.get(_PALLAS_VAR)!r}")
     key = name if pallas is None else f"jax+pallas-{pallas}"
     if key not in _INSTANCES:
         if name == "numpy":

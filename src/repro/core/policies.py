@@ -36,7 +36,7 @@ from typing import Callable
 
 import numpy as np
 
-from repro.core.backend import get_backend
+from repro.core.backend import PallasDeviceUnsupported, get_backend
 from repro.core.context import CompilationContext
 from repro.core.goals import MinEnergy, MinLatency
 from repro.core.greedy import solve_greedy
@@ -90,9 +90,10 @@ class OrchestratorConfig:
     backend: str | None = None
     # Pallas kernel mode for the jax backend: None → $PFDNN_PALLAS (or
     # off); "interpret" runs the fused dp_sweep kernels in interpret
-    # mode (CPU-safe, bit-identical — the tier-1 correctness mode),
-    # "device" compiles them for the accelerator.  Ignored for the
-    # numpy backend; rewritten into the backend name in __post_init__.
+    # mode (CPU-safe, bit-identical — the kernels' correctness vehicle);
+    # "device" is refused (PallasDeviceUnsupported: the TPU compiler
+    # rejects the kernels).  Rewritten into the backend name in
+    # __post_init__.
     pallas: str | None = None
     # rail-sweep fan-out: worker threads for select_rails (None →
     # $PFDNN_WORKERS or serial).  The parallel sweep selects the same
@@ -113,13 +114,15 @@ class OrchestratorConfig:
 
     def __post_init__(self):
         if self.pallas is not None:
-            if self.pallas not in ("interpret", "device"):
+            if self.pallas == "device":
+                raise PallasDeviceUnsupported(
+                    "OrchestratorConfig(pallas='device')")
+            if self.pallas != "interpret":
                 raise ValueError(
-                    f"pallas={self.pallas!r}: expected None, "
-                    "'interpret' or 'device'")
+                    f"pallas={self.pallas!r}: expected None or "
+                    "'interpret'")
             if self.backend in (None, "jax"):
-                self.backend = "jax-pallas" if self.pallas == "device" \
-                    else "jax-pallas-interpret"
+                self.backend = "jax-pallas-interpret"
             elif self.backend == "numpy":
                 raise ValueError(
                     "pallas= requires the jax backend; backend='numpy' "

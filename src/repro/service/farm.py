@@ -36,6 +36,12 @@ admission, same merged batches — the deterministic vehicle for tests);
 ``n_workers>=1`` spawns that many worker processes.  Workers default to
 the ``spawn`` start method so they never inherit jax/thread state from
 the parent.
+
+On the jax backend a worker owns the accelerator.  A jax process opens
+every TPU chip of its host, and a second process cannot open them
+while the first lives, so on a TPU host the farm starts at most ONE
+jax worker, and refuses to start while the calling process itself has
+initialised jax (it would hold the chips the worker needs).
 """
 
 from __future__ import annotations
@@ -51,6 +57,11 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core.backend import (
+    is_jax_backend,
+    jax_backend_initialized,
+    local_tpu_chips,
+)
 from repro.hw.edge40nm import EDGE40NM_DEFAULT, Edge40nmAccelerator
 from repro.service.compile_service import CompileRequest, CompileService
 from repro.service.store import ArtifactStore
@@ -121,11 +132,13 @@ def _counters_delta(now: dict, base: dict) -> dict:
 
 def _farm_worker(worker_id: int, disk_path: str,
                  acc: Edge40nmAccelerator, use_schedule_cache: bool,
-                 task_q, result_q) -> None:
+                 backend: str | None, task_q, result_q) -> None:
     """Worker process main: pull admitted batches, run each as one
     ``compile_many`` against the shared disk store, ship results (and
     the batch's store-counter deltas) back.  A ``None`` task is the
     shutdown sentinel."""
+    if backend is not None:     # the default of backend-less requests
+        os.environ["PFDNN_BACKEND"] = backend
     svc = CompileService(acc, store=ArtifactStore(disk_path=disk_path),
                          use_schedule_cache=use_schedule_cache)
     base = _stats_counters(svc.store)
@@ -177,6 +190,11 @@ class CompileFarm:
     ``submit`` may be called repeatedly (also between ``drain`` calls);
     batches are formed lazily as workers free up, so late-arriving
     tenants are admitted fairly against an existing backlog.
+
+    ``backend`` is the solver backend the workers compile on (``None``
+    → ``$PFDNN_BACKEND`` or numpy): requests without one of their own
+    run on it, and a jax backend places the workers on the host's chips
+    (see module docstring).
     """
 
     def __init__(self, disk_path, *, n_workers: int = 2,
@@ -184,7 +202,8 @@ class CompileFarm:
                  batch_size: int = 16,
                  use_schedule_cache: bool = True,
                  mp_context: str = "spawn",
-                 max_disk_bytes: int | None = None):
+                 max_disk_bytes: int | None = None,
+                 backend: str | None = None):
         if n_workers < 0:
             raise ValueError(f"n_workers must be >= 0, got {n_workers}")
         if batch_size < 1:
@@ -196,6 +215,7 @@ class CompileFarm:
         self.batch_size = batch_size
         self.use_schedule_cache = use_schedule_cache
         self.mp_context = mp_context
+        self.backend = backend
         # build (and budget) the tier eagerly so a bad path or an
         # incompatible schema fails at construction, not in a worker
         ArtifactStore(disk_path=self.disk_path,
@@ -216,6 +236,7 @@ class CompileFarm:
     def start(self) -> "CompileFarm":
         if self.n_workers == 0 or self._procs:
             return self
+        self._check_chip_placement()
         ctx = multiprocessing.get_context(self.mp_context)
         self._task_q = ctx.Queue()
         self._result_q = ctx.Queue()
@@ -230,8 +251,8 @@ class CompileFarm:
                 p = ctx.Process(
                     target=_farm_worker,
                     args=(wid, self.disk_path, self.acc,
-                          self.use_schedule_cache, self._task_q,
-                          self._result_q),
+                          self.use_schedule_cache, self.backend,
+                          self._task_q, self._result_q),
                     daemon=True)
                 p.start()
                 self._procs.append(p)
@@ -241,6 +262,28 @@ class CompileFarm:
             else:
                 os.environ["PYTHONPATH"] = old_pp
         return self
+
+    def _check_chip_placement(self) -> None:
+        """Refuse a worker set that cannot own the host's TPU chips:
+        more than one jax worker, or a parent that already holds them."""
+        if not is_jax_backend(self.backend):
+            return
+        chips = local_tpu_chips()
+        if not chips:
+            return
+        if self.n_workers > 1:
+            raise ValueError(
+                f"CompileFarm(n_workers={self.n_workers}) on the jax "
+                f"backend: this host has {chips} TPU chip(s) and a jax "
+                "process opens all of them, so a second worker could "
+                "not open any; use n_workers=1 (or the numpy backend "
+                "for more processes)")
+        if jax_backend_initialized():
+            raise RuntimeError(
+                "CompileFarm on the jax backend: this process has "
+                "already initialised jax and holds the host's TPU "
+                "chips, so the worker could not open them; start the "
+                "farm before this process touches jax")
 
     def close(self) -> None:
         """Shut the farm down: workers drain their queued batches, get
@@ -271,6 +314,14 @@ class CompileFarm:
         the ``drain`` result dict).  Enqueue time is stamped here —
         reported latencies include every queueing delay the tenant
         actually saw."""
+        for req in requests:
+            named = req.cfg.backend if req.cfg is not None else None
+            if named is not None and is_jax_backend(named) \
+                    and not is_jax_backend(self.backend):
+                raise ValueError(
+                    f"request backend {named!r} runs on jax but "
+                    "the farm's workers are not placed for jax; give "
+                    "CompileFarm(backend=...) the same backend")
         uids = []
         now = time.perf_counter()
         for req in requests:

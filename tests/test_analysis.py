@@ -77,6 +77,29 @@ def test_certify_clean_policies(specs, policy):
                                                    rel=1e-9)
 
 
+def test_certify_zero_slack_min_latency_artifact(golden_sched, specs):
+    """A MinLatency artifact records ``t_max = t_infer`` and no idle
+    energy; the re-derived latency may sit an ulp below it, which
+    prices a ~1e-21 J idle interval — not a ledger mismatch."""
+    from repro.core import compile as compile_goal
+    from repro.core.goals import MinLatency
+
+    budget = 1.3 * (golden_sched.e_op + golden_sched.e_trans)
+    sched = compile_goal(
+        specs, MinLatency(energy_budget_j=budget),
+        cfg=OrchestratorConfig(policy="pfdnn", n_max_rails=N_RAILS),
+        network=NETWORK)
+    assert sched.e_idle == 0.0 and sched.t_max == sched.t_infer
+    cert = certify(sched, specs, acc=ACC, n_max_rails=N_RAILS)
+    assert cert.ok, cert.summary()
+    # a real idle-energy error is still flagged at the same tolerance
+    bad = dataclasses.replace(sched, e_idle=1e-6 * sched.e_total,
+                              e_total=sched.e_total * (1 + 1e-6))
+    kinds = {v.kind for v in certify(bad, specs, acc=ACC,
+                                     n_max_rails=N_RAILS).violations}
+    assert ENERGY_MISMATCH in kinds
+
+
 def test_certify_dual_bound(golden_sched, specs):
     cert = certify(golden_sched, specs, acc=ACC, n_max_rails=N_RAILS)
     assert cert.dual is not None

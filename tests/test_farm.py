@@ -460,6 +460,46 @@ def test_farm_validates_arguments(tmp_path):
         CompileFarm(tmp_path / "bad")   # fails at construction
 
 
+def test_farm_places_jax_workers_on_the_chips(tmp_path, monkeypatch):
+    """On a TPU host a jax farm refuses a second worker (a jax process
+    opens every local chip) and refuses to start from a parent that
+    already holds the chips; off TPU, or on numpy, nothing is refused.
+    Every refusal comes before a worker process is spawned."""
+    import repro.service.farm as farm_mod
+
+    monkeypatch.setattr(farm_mod, "local_tpu_chips", lambda: 1)
+    monkeypatch.setattr(farm_mod, "jax_backend_initialized", lambda: False)
+    with pytest.raises(ValueError, match="n_workers=2"):
+        CompileFarm(tmp_path / "s", n_workers=2, backend="jax").start()
+    monkeypatch.setenv("PFDNN_BACKEND", "jax")     # the default backend
+    with pytest.raises(ValueError, match="n_workers=2"):
+        CompileFarm(tmp_path / "s", n_workers=2).start()
+    monkeypatch.setattr(farm_mod, "jax_backend_initialized", lambda: True)
+    with pytest.raises(RuntimeError, match="already initialised jax"):
+        CompileFarm(tmp_path / "s", n_workers=1, backend="jax").start()
+    # numpy workers never touch the chips; no chips, nothing to place
+    CompileFarm(tmp_path / "s", n_workers=4,
+                backend="numpy")._check_chip_placement()
+    monkeypatch.setattr(farm_mod, "local_tpu_chips", lambda: 0)
+    CompileFarm(tmp_path / "s", n_workers=4,
+                backend="jax")._check_chip_placement()
+
+
+def test_farm_submit_rejects_jax_requests_on_a_non_jax_farm(tmp_path):
+    key = "squeezenet1.1|0.9|2|pfdnn"
+    network, rate, cfg = _cfg_for(key)
+    req = CompileRequest(edge_network(network), rate,
+                         OrchestratorConfig(policy=cfg.policy,
+                                            backend="jax"),
+                         network=network)
+    farm = CompileFarm(tmp_path / "s", n_workers=0, backend="numpy")
+    with pytest.raises(ValueError, match="CompileFarm\\(backend"):
+        farm.submit("A", [req])
+    assert farm.pending() == 0
+    CompileFarm(tmp_path / "s", n_workers=0, backend="jax").submit(
+        "A", [req])
+
+
 def test_farm_cross_process_shared_warm(tmp_path):
     """The real thing: a 2-worker spawn farm compiles cold; a second
     farm with *fresh worker processes* over the same directory answers

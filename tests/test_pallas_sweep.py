@@ -36,6 +36,7 @@ from repro.core import (
 )
 from repro.core.backend import (
     BucketStack,
+    PallasDeviceUnsupported,
     PendingResult,
     StackCaches,
     build_padded,
@@ -286,6 +287,33 @@ def test_lanes_api_matches_member_stack_and_counts_uploads(rng):
         mark["kernel_dispatches"] + 3
 
 
+def test_scan_lanes_price_paths_on_the_host(monkeypatch, rng):
+    """With the CPU routing off (as on a TPU) the scan backend's DP
+    runs on the device mirror, which holds only the DP operands, while
+    path costs come from the store's host tensors — no dispatch, and
+    numpy's ledger to the last bit."""
+    jb = get_backend("jax")
+    monkeypatch.setattr(jb, "_cpu", False)
+    store, lanes = _lane_store(rng)
+    base = dict(jb.io_stats)
+    w = rng.random((len(lanes), 4))
+    np.testing.assert_array_equal(
+        jb.dp_multi_lanes(store, lanes, w, w),
+        get_backend("numpy").dp_multi_stacked(
+            jb._host_member_stack(store, lanes), w, w))
+    assert jb.io_stats["h2d_lane_bytes"] - base["h2d_lane_bytes"] == sum(
+        getattr(store, nm)[:len(lanes)].nbytes
+        for nm in ("_t_op", "_e_op", "_valid", "_t_trans", "_e_trans"))
+    mark = dict(jb.io_stats)
+    pl = np.asarray([0, 2, 1, 1], dtype=np.int64)
+    pp_ = rng.integers(0, 5, (4, 4)).astype(np.int64)
+    got = jb.path_costs_lanes(store, pl, pp_)
+    assert jb.io_stats == mark
+    exp = get_backend("numpy").path_costs_stacked(store.view(), pl, pp_)
+    for k in exp:
+        np.testing.assert_array_equal(got[k], exp[k], err_msg=k)
+
+
 def test_lane_admission_uploads_only_the_new_lane(rng):
     """Growing a warm store re-uses the resident mirror: admitting one
     more lane uploads exactly that lane."""
@@ -370,8 +398,8 @@ def test_pending_result_defers_and_memoizes():
 def test_orchestrator_config_pallas_validation():
     cfg = OrchestratorConfig(backend="jax", pallas="interpret")
     assert cfg.backend == "jax-pallas-interpret"
-    cfg = OrchestratorConfig(pallas="device")
-    assert cfg.backend == "jax-pallas"
+    with pytest.raises(PallasDeviceUnsupported, match="ROADMAP"):
+        OrchestratorConfig(pallas="device")
     with pytest.raises(ValueError, match="pallas"):
         OrchestratorConfig(pallas="nope")
     with pytest.raises(ValueError, match="numpy"):

@@ -50,6 +50,31 @@ BACKENDS = list(available_backends())
 
 # --------------------------------------- backend k-best frontier parity
 
+@pytest.mark.parametrize("shape", [(3, 40, 5), (3, 40)])
+def test_jax_smallest_k_is_numpy_stable_order(shape):
+    """The jax k-best's selection (k rounds of min + first hit, not a
+    sort) returns numpy's stable argsort prefix exactly — heavy ties,
+    inf ties and all-inf columns included."""
+    pytest.importorskip("jax")
+    import jax
+
+    jb = get_backend("jax")
+    k = 10
+    rng = np.random.default_rng(0)
+    for trial in range(12):
+        x = rng.integers(0, 6, shape).astype(float)
+        x[x == 5] = np.inf
+        if trial % 3 == 0:
+            x[:, :k + 3] = np.inf          # inf ties ahead of finite ones
+        if trial % 4 == 0:
+            x[0] = np.inf                  # a row with nothing finite
+        with jax.enable_x64(True):
+            got = np.asarray(jax.jit(
+                lambda a: jb._smallest_k(a, k))(x))
+        want = np.argsort(x, axis=1, kind="stable")[:, :k]
+        np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("seed", range(4))
 def test_kbest_multi_matches_scalar_kernel(backend, seed):
