@@ -8,7 +8,7 @@ Usage:
     PYTHONPATH=src python benchmarks/sweep_speed.py \
         [--out BENCH_sweep.json] [--record-baseline] [--smoke] \
         [--backend numpy|jax|jax-pallas-interpret] \
-        [--workers N] [--profile [DIR]]
+        [--workers N]
 
 ``--record-baseline`` writes ``benchmarks/baseline_sweep.json`` instead
 (run once against the implementation you want to compare against).  When
@@ -37,10 +37,6 @@ row records the backend transfer counters for its LAST rep —
 uploads (one per newly admitted rail-subset lane; warm rounds add
 zero) and ``kernel_dispatches`` counts device lane-kernel launches,
 so bytes-per-dispatch ≈ 0 is the device-resident steady state.
-
-``--profile DIR`` captures a jax profiler trace of one warm sweep
-compile (jit caches pre-warmed by an untraced run) for TensorBoard /
-Perfetto; DIR defaults to ``benchmarks/trace``.
 """
 
 from __future__ import annotations
@@ -210,21 +206,6 @@ def smoke_backend_compare(reps: int = 3) -> dict[str, dict]:
     return out
 
 
-def profile_trace(backend: str | None, outdir: str) -> None:
-    """One warm sweep compile under ``jax.profiler.trace`` (an untraced
-    run first pays the jit compiles, so the trace shows the steady
-    state: lane kernels and D2H result collection, no tracing)."""
-    import jax
-
-    (network, frac), = SMOKE_CONFIGS
-    rate = max_rate(network) * frac
-    schedule_for(network, rate, "pfdnn", n_max_rails=2, backend=backend)
-    with jax.profiler.trace(outdir):
-        schedule_for(network, rate, "pfdnn", n_max_rails=2,
-                     backend=backend)
-    print(f"jax trace written to {outdir}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=str(HERE.parent / "BENCH_sweep.json"))
@@ -245,19 +226,8 @@ def main() -> None:
                          "$PFDNN_WORKERS or serial)")
     ap.add_argument("--no-stack", action="store_true",
                     help="legacy per-subset sweep (stack_subsets=False)")
-    ap.add_argument("--profile", metavar="DIR", nargs="?",
-                    const=str(HERE / "trace"), default=None,
-                    help="write a jax profiler trace of one warm sweep "
-                         "compile to DIR (default benchmarks/trace) "
-                         "and exit; requires a jax backend")
     args = ap.parse_args()
     configure_compile_cache()
-
-    if args.profile is not None:
-        if args.backend == "numpy":
-            ap.error("--profile requires a jax backend")
-        profile_trace(args.backend or "jax", args.profile)
-        return
 
     results = run_sweeps(smoke=args.smoke, backend=args.backend,
                          workers=args.workers, stack=not args.no_stack)

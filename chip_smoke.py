@@ -43,41 +43,6 @@ N_FRONTIER = 8
 BUDGET_FACTOR = 1.3
 
 
-class _CompileEvents:
-    """JAX monitoring listener: persistent-cache traffic and backend
-    compile times of this process."""
-
-    # jax records a persistent-cache *write* under this name
-    _WRITE = "/jax/compilation_cache/cache_misses"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.hits = 0
-        self.writes = 0
-        self.compile_s: list[float] = []
-
-    def event(self, name, **_):
-        if name == self._HIT:
-            self.hits += 1
-        elif name == self._WRITE:
-            self.writes += 1
-
-    def duration(self, name, secs, **_):
-        if name == self._COMPILE:
-            self.compile_s.append(secs)
-
-    def summary(self, min_compile_s: float) -> dict:
-        c = sorted(self.compile_s)
-        return {"cache_hits": self.hits, "cache_writes": self.writes,
-                "backend_compiles": len(c),
-                "compile_s_total": sum(c),
-                "compile_s_median": c[len(c) // 2] if c else None,
-                "compile_s_max": c[-1] if c else None,
-                "compiles_at_or_above_cache_threshold":
-                    sum(s >= min_compile_s for s in c)}
-
-
 def build_requests(backend: str, budget_j: float | None, *,
                    networks=NETWORKS, frontier_net=FRONTIER_NET,
                    n_frontier=N_FRONTIER, n_max_rails=N_MAX_RAILS):
@@ -231,23 +196,34 @@ def smoke(**sizes) -> tuple[list[str], dict]:
     return failures, report
 
 
+def _compile_summary(events, min_compile_s: float) -> dict:
+    """The programs JAX built (each records a compile time, also when
+    it was loaded from the persistent cache) and the cache's hits; the
+    cache's writes show in its entry counts."""
+    c = sorted(events.compile_s)
+    return {"cache_hits": events.hits, "backend_compiles": len(c),
+            "compile_s_total": sum(c),
+            "compile_s_median": c[len(c) // 2] if c else None,
+            "compile_s_max": c[-1] if c else None,
+            "compiles_at_or_above_cache_threshold":
+                sum(s >= min_compile_s for s in c)}
+
+
 def main() -> int:
     sys.path.insert(0, str(ROOT / "src"))
     try:
         from repro.core.backend import (configure_compile_cache,
                                         local_tpu_chips)
         import benchmarks.common  # noqa: F401
+        from chipbench.device import CompileEvents
     except ImportError as exc:
         print(f"chip_smoke: the repository is not next to this script "
               f"({exc})", file=sys.stderr)
         return 2
     cache_dir = configure_compile_cache()
     import jax
-    import jax.monitoring
 
-    events = _CompileEvents()
-    jax.monitoring.register_event_listener(events.event)
-    jax.monitoring.register_event_duration_secs_listener(events.duration)
+    events = CompileEvents()
     try:
         devices = jax.devices()
     except RuntimeError as exc:
@@ -275,7 +251,8 @@ def main() -> int:
                         f"{len(devices)} TPU devices")
     min_s = jax.config.values["jax_persistent_cache_min_compile_time_secs"]
     report["compile_cache"] = dict(
-        events.summary(min_s), dir=cache_dir, min_compile_time_s=min_s,
+        _compile_summary(events, min_s), dir=cache_dir,
+        min_compile_time_s=min_s,
         entries_before=cache_before,
         entries_after=sum(1 for _ in pathlib.Path(cache_dir).rglob("*")))
     print(f"compile cache: {report['compile_cache']}")

@@ -90,6 +90,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import spans
+
 _ENV_VAR = "PFDNN_BACKEND"
 _DEFAULT = "numpy"
 
@@ -935,9 +937,12 @@ class JaxBackend:
         self._lanes_jits: dict[tuple[str, int], object] = {}
         # host→device traffic and dispatch accounting for the
         # device-lane path (benches and transfer-counting tests read
-        # this; increments are stats-only, so no lock)
+        # this; increments are stats-only, so no lock); lane_slots
+        # counts the (lane, column) cells the DP and k-best dispatches
+        # computed, lane_slots_used those that were not padding
         self.io_stats = {"h2d_lane_uploads": 0, "h2d_lane_bytes": 0,
-                         "kernel_dispatches": 0}
+                         "kernel_dispatches": 0, "lane_slots": 0,
+                         "lane_slots_used": 0}
         # On CPU hosts the jitted programs only pay for themselves on
         # reduction-heavy work: gather-bound path evaluation and tiny
         # DP slabs are dominated by dispatch + host↔device copies, so
@@ -949,9 +954,11 @@ class JaxBackend:
         # same-shape lane-block rebuilds donate the old device buffer
         # on real accelerators (donation on CPU is a no-op jax warns
         # about, so it is skipped there)
+        def pfdnn_lane_block_set(arr, blk, b):
+            return jax.lax.dynamic_update_slice_in_dim(arr, blk, b, 0)
+
         self._set_block = jax.jit(
-            lambda arr, blk, b: jax.lax.dynamic_update_slice_in_dim(
-                arr, blk, b, 0),
+            pfdnn_lane_block_set,
             donate_argnums=() if self._cpu else (0,))
 
     # backtracking and the DP share one compiled program; float64 is
@@ -1324,7 +1331,8 @@ class JaxBackend:
                 return m
             jnp = self._jax.numpy
             host = [getattr(store, nm) for nm in names]
-            with self._x64():
+            with self._x64(), spans.span(spans.LANES_UPLOAD,
+                                         lanes=store.n - m.n):
                 if m.cap != cap:
                     old = m.arrays or (None,) * len(host)
                     grown = []
@@ -1384,7 +1392,10 @@ class JaxBackend:
     def _lanes_fn(self, kind: str, k: int = 0):
         """Jitted lane-gather program per (kind, k): the mirror arrays
         go in whole and the lane gather happens ON DEVICE, so the only
-        host→device traffic per call is the index/weight rows."""
+        host→device traffic per call is the index/weight rows.  The
+        program is named ``pfdnn_<kind>_lanes`` (one name for every k),
+        so its XLA module reads ``jit_pfdnn_<kind>_lanes`` in a
+        profiler trace."""
         key = (kind, k)
         fn = self._lanes_jits.get(key)
         if fn is not None:
@@ -1428,6 +1439,7 @@ class JaxBackend:
                     lanes, paths, t_op, e_op, tt, et, sw, interpret=True)
         else:
             raise ValueError(f"unknown lanes kernel {kind!r}")
+        impl.__name__ = impl.__qualname__ = f"pfdnn_{kind}_lanes"
         fn = jax.jit(impl)
         return self._lanes_jits.setdefault(key, fn)
 
@@ -1445,6 +1457,13 @@ class JaxBackend:
             rows = [np.concatenate(
                 [r, np.repeat(r[:1], Bp - B, axis=0)]) for r in rows]
         return idx, rows, B
+
+    def _count_dispatch(self, slots: int, used: int) -> None:
+        """Tally one DP or k-best dispatch of ``slots`` (lane, column)
+        cells, ``used`` of them real lanes and columns."""
+        self.io_stats["kernel_dispatches"] += 1
+        self.io_stats["lane_slots"] += slots
+        self.io_stats["lane_slots_used"] += used
 
     def dp_multi_lanes(self, store: BucketStack, lanes: Sequence[int],
                        w_e: np.ndarray, w_t: np.ndarray, *,
@@ -1470,7 +1489,7 @@ class JaxBackend:
         with self._x64():
             dev = fn(*m.arrays[:5], jnp.asarray(idx),
                      jnp.asarray(w), jnp.asarray(t))
-        self.io_stats["kernel_dispatches"] += 1
+        self._count_dispatch(len(idx) * w.shape[1], B * K)
         pend = PendingResult(
             lambda: np.asarray(dev, dtype=np.int64)[:B, :K])
         return pend if defer else pend.get()
@@ -1495,7 +1514,7 @@ class JaxBackend:
         with self._x64():
             dev_p, dev_c = fn(*m.arrays[:5], jnp.asarray(idx),
                               jnp.asarray(mr))
-        self.io_stats["kernel_dispatches"] += 1
+        self._count_dispatch(len(idx) * mr.shape[1], B * K)
         pend = PendingResult(lambda: (
             np.asarray(dev_p, dtype=np.int64)[:B, :K],
             np.asarray(dev_c, dtype=np.int64)[:B, :K]))

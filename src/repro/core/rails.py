@@ -15,8 +15,10 @@ from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.backend import PendingResult, StackCaches, get_backend
 from repro.core.refinement import move_scores
+from repro.core.spans import span
 
 
 def all_rail_subsets(levels: Sequence[float],
@@ -626,170 +628,196 @@ def run_stacked_sweeps(
             tasks[0].lane_store.lane_pad_for(len(tasks)))
         return stack
 
-    try:
-        admit_all()
-        while any(sw.active for sw in sweeps):
-            active = [t for sw in sweeps for t in sw.active]
-            fleet["stacked_rounds"] += 1
-            # -- kernel phase: one stacked call per request-shape group.
-            # Groups are per padded bucket: small-bucket subsets never pay
-            # a wide bucket's reduction widths (the kernels additionally
-            # slice down to the group's widest valid prefix).  Tasks of
-            # different sweeps group together whenever their buckets and
-            # batch shapes match — the cross-network stacking.
-            groups: dict[tuple, list] = {}
-            for task in active:
-                req = task.request
-                # device-lane backends read operands from the per-store
-                # mirror, so groups must share one lane store — key by
-                # bucket signature (it embeds the (L, S) bucket); host
-                # backends keep the wider shape-only grouping
-                bucket = task.bucket_sig if lanes_api else task.bucket
-                if req.kind == "dp":
-                    key = ("dp", bucket, len(req.w_e))
-                elif req.kind == "kbest":
-                    key = ("kbest", bucket, len(req.mus), req.k)
-                elif req.kind == "moves":
-                    # move scoring folds in the deadline/idle math, so the
-                    # group additionally keys on (t_max, idle); the lanes
-                    # must live in one store, hence the bucket signature
-                    key = ("moves", task.bucket_sig,
-                           task.problem.t_max, task.problem.idle)
-                else:                   # "eval"/"eval_batch": no kernel
-                    continue
-                groups.setdefault(key, []).append(task)
-            raw: dict[int, object] = {}
-            # dispatch EVERY group before collecting any result: on an
-            # async-dispatch backend the device works through the whole
-            # round while Python stages the remaining groups
-            inflight: list[tuple[tuple, list, PendingResult]] = []
-            for key, tasks in groups.items():
-                fleet["stacked_calls"] += 1
-                if key[0] == "dp":
-                    w_e = np.stack([t.request.w_e for t in tasks])
-                    w_t = np.stack([t.request.w_t for t in tasks])
-                    if lanes_api:
-                        pend = bk.dp_multi_lanes(
-                            tasks[0].lane_store,
-                            [t.lane for t in tasks], w_e, w_t,
-                            defer=True)
-                    else:
-                        pend = PendingResult.ready(
-                            bk.dp_multi_stacked(stack_for(tasks),
-                                                w_e, w_t))
-                elif key[0] == "kbest":
-                    mus = np.stack([np.asarray(t.request.mus, dtype=float)
-                                    for t in tasks])
-                    if lanes_api:
-                        pend = bk.kbest_multi_lanes(
-                            tasks[0].lane_store,
-                            [t.lane for t in tasks], mus, key[3],
-                            defer=True)
-                    else:
-                        pend = PendingResult.ready(
-                            bk.kbest_multi_stacked(stack_for(tasks),
-                                                   mus, key[3]))
-                else:                                 # refinement moves
-                    counts = [len(t.request.paths) for t in tasks]
-                    bs = tasks[0].lane_store
-                    lanes = np.concatenate(
-                        [np.full(n, t.lane, dtype=np.int64)
-                         for t, n in zip(tasks, counts)])
-                    pa = np.concatenate([t.request.paths for t in tasks])
-                    t_inf = np.concatenate([t.request.aux[0] for t in tasks])
-                    e_idl = np.concatenate([t.request.aux[1] for t in tasks])
+    def group(active) -> dict[tuple, list]:
+        # -- kernel phase: one stacked call per request-shape group.
+        # Groups are per padded bucket: small-bucket subsets never pay
+        # a wide bucket's reduction widths (the kernels additionally
+        # slice down to the group's widest valid prefix).  Tasks of
+        # different sweeps group together whenever their buckets and
+        # batch shapes match — the cross-network stacking.
+        groups: dict[tuple, list] = {}
+        for task in active:
+            req = task.request
+            # device-lane backends read operands from the per-store
+            # mirror, so groups must share one lane store — key by
+            # bucket signature (it embeds the (L, S) bucket); host
+            # backends keep the wider shape-only grouping
+            bucket = task.bucket_sig if lanes_api else task.bucket
+            if req.kind == "dp":
+                key = ("dp", bucket, len(req.w_e))
+            elif req.kind == "kbest":
+                key = ("kbest", bucket, len(req.mus), req.k)
+            elif req.kind == "moves":
+                # move scoring folds in the deadline/idle math, so the
+                # group additionally keys on (t_max, idle); the lanes
+                # must live in one store, hence the bucket signature
+                key = ("moves", task.bucket_sig,
+                       task.problem.t_max, task.problem.idle)
+            else:                   # "eval"/"eval_batch": no kernel
+                continue
+            groups.setdefault(key, []).append(task)
+        return groups
+
+    def dispatch(groups) -> list[tuple[tuple, list, PendingResult]]:
+        # dispatch EVERY group before collecting any result: on an
+        # async-dispatch backend the device works through the whole
+        # round while Python stages the remaining groups
+        inflight: list[tuple[tuple, list, PendingResult]] = []
+        for key, tasks in groups.items():
+            fleet["stacked_calls"] += 1
+            if key[0] == "dp":
+                w_e = np.stack([t.request.w_e for t in tasks])
+                w_t = np.stack([t.request.w_t for t in tasks])
+                if lanes_api:
+                    pend = bk.dp_multi_lanes(
+                        tasks[0].lane_store,
+                        [t.lane for t in tasks], w_e, w_t,
+                        defer=True)
+                else:
+                    pend = PendingResult.ready(
+                        bk.dp_multi_stacked(stack_for(tasks),
+                                            w_e, w_t))
+            elif key[0] == "kbest":
+                mus = np.stack([np.asarray(t.request.mus, dtype=float)
+                                for t in tasks])
+                if lanes_api:
+                    pend = bk.kbest_multi_lanes(
+                        tasks[0].lane_store,
+                        [t.lane for t in tasks], mus, key[3],
+                        defer=True)
+                else:
+                    pend = PendingResult.ready(
+                        bk.kbest_multi_stacked(stack_for(tasks),
+                                               mus, key[3]))
+            else:                                 # refinement moves
+                counts = [len(t.request.paths) for t in tasks]
+                bs = tasks[0].lane_store
+                lanes = np.concatenate(
+                    [np.full(n, t.lane, dtype=np.int64)
+                     for t, n in zip(tasks, counts)])
+                pa = np.concatenate([t.request.paths for t in tasks])
+                t_inf = np.concatenate([t.request.aux[0] for t in tasks])
+                e_idl = np.concatenate([t.request.aux[1] for t in tasks])
+                with span(spans.ROUND_MOVES):
                     pend = PendingResult.ready(move_scores(
                         bs.view(), lanes, pa, t_inf, e_idl,
                         key[2], key[3]))
-                inflight.append((key, tasks, pend))
-            for key, tasks, pend in inflight:       # round barrier
-                if key[0] == "dp":
-                    paths = pend.get()
-                    for b, t in enumerate(tasks):
-                        raw[t.uid] = paths[b]
-                elif key[0] == "kbest":
-                    paths, counts = pend.get()
-                    for b, t in enumerate(tasks):
-                        raw[t.uid] = (paths[b], counts[b])
+            inflight.append((key, tasks, pend))
+        return inflight
+
+    def collect(inflight) -> dict[int, object]:
+        # the round barrier: wait for the device, read results back
+        raw: dict[int, object] = {}
+        for key, tasks, pend in inflight:
+            if key[0] == "dp":
+                paths = pend.get()
+                for b, t in enumerate(tasks):
+                    raw[t.uid] = paths[b]
+            elif key[0] == "kbest":
+                paths, counts = pend.get()
+                for b, t in enumerate(tasks):
+                    raw[t.uid] = (paths[b], counts[b])
+            else:
+                mv_layer, mv_state, mv_gain = pend.get()
+                off = 0
+                for t in tasks:
+                    n = len(t.request.paths)
+                    raw[t.uid] = (mv_layer[off:off + n],
+                                  mv_state[off:off + n],
+                                  mv_gain[off:off + n])
+                    off += n
+        return raw
+
+    def evaluate(active, raw) -> None:
+        # -- evaluation phase: ONE stacked cost gather per bucket for
+        # every fresh path of the round, then advance each machine.
+        # Machines whose next request is evaluation-only (no kernel
+        # needed) are served again within the same round, so pure-eval
+        # rounds never exist.
+        todo = active
+        while todo:
+            fresh = {t.uid: t.take_kernel(raw.pop(t.uid, None))
+                     for t in todo}
+            by_bucket: dict[tuple, dict[tuple, list]] = {}
+            for t in todo:
+                if len(fresh[t.uid]):
+                    fin = (t.problem.t_max, t.problem.idle)
+                    by_bucket.setdefault(t.bucket_sig, {}) \
+                        .setdefault(fin, []).append(t)
+            # dispatch every bucket's gather, then collect — same
+            # async overlap as the kernel phase
+            evals: list[tuple[dict, np.ndarray, PendingResult]] = []
+            for sig, fin_groups in by_bucket.items():
+                need = [t for sub in fin_groups.values() for t in sub]
+                bs = need[0].lane_store
+                lanes = np.concatenate(
+                    [np.full(len(fresh[t.uid]), t.lane,
+                             dtype=np.int64) for t in need])
+                paths = np.concatenate([fresh[t.uid] for t in need])
+                fleet["stacked_calls"] += 1
+                if lanes_api:
+                    pend = bk.path_costs_lanes(bs, lanes, paths,
+                                               defer=True)
                 else:
-                    mv_layer, mv_state, mv_gain = pend.get()
-                    off = 0
-                    for t in tasks:
-                        n = len(t.request.paths)
-                        raw[t.uid] = (mv_layer[off:off + n],
-                                      mv_state[off:off + n],
-                                      mv_gain[off:off + n])
-                        off += n
-            # -- evaluation phase: ONE stacked cost gather per bucket for
-            # every fresh path of the round, then advance each machine.
-            # Machines whose next request is evaluation-only (no kernel
-            # needed) are served again within the same round, so pure-eval
-            # rounds never exist.
-            todo = active
-            while todo:
-                fresh = {t.uid: t.take_kernel(raw.pop(t.uid, None))
-                         for t in todo}
-                by_bucket: dict[tuple, dict[tuple, list]] = {}
-                for t in todo:
-                    if len(fresh[t.uid]):
-                        fin = (t.problem.t_max, t.problem.idle)
-                        by_bucket.setdefault(t.bucket_sig, {}) \
-                            .setdefault(fin, []).append(t)
-                # dispatch every bucket's gather, then collect — same
-                # async overlap as the kernel phase
-                evals: list[tuple[dict, np.ndarray, PendingResult]] = []
-                for sig, fin_groups in by_bucket.items():
-                    need = [t for sub in fin_groups.values() for t in sub]
-                    bs = need[0].lane_store
-                    lanes = np.concatenate(
-                        [np.full(len(fresh[t.uid]), t.lane,
-                                 dtype=np.int64) for t in need])
-                    paths = np.concatenate([fresh[t.uid] for t in need])
-                    fleet["stacked_calls"] += 1
-                    if lanes_api:
-                        pend = bk.path_costs_lanes(bs, lanes, paths,
-                                                   defer=True)
-                    else:
-                        pend = PendingResult.ready(
-                            bk.path_costs_stacked(bs.view(), lanes,
-                                                  paths))
-                    evals.append((fin_groups, paths, pend))
-                for fin_groups, paths, pend in evals:   # round barrier
-                    costs = pend.get()
-                    # the deadline/idle finishing math is shared per
-                    # (t_max, idle) subgroup — one vectorized pass each,
-                    # row-identical to per-task evaluation
-                    off = 0
-                    for sub in fin_groups.values():
-                        n_sub = sum(len(fresh[t.uid]) for t in sub)
-                        batch = sub[0].problem.finish_costs(
-                            paths[off:off + n_sub],
-                            {ck: val[off:off + n_sub]
-                             for ck, val in costs.items()})
-                        soff = 0
-                        for t in sub:
-                            n = len(fresh[t.uid])
-                            t.take_rows({ck: val[soff:soff + n]
-                                         for ck, val in batch.items()})
-                            soff += n
-                        off += n_sub
-                for t in todo:
-                    if len(fresh[t.uid]) == 0:
-                        t.take_rows(None)
-                todo = [t for t in todo if t.request is not None
-                        and t.request.kind in ("eval", "eval_batch")]
-            # -- bookkeeping phase: completions, cuts, admission
-            for sw in sweeps:
-                still = []
-                for task in sw.active:
-                    if task.request is None:
-                        sw.finish(task)
-                        caches.evict_members(task.uid)
-                        live_uids.discard(task.uid)
-                    else:
-                        still.append(task)
-                sw.active = still
+                    pend = PendingResult.ready(
+                        bk.path_costs_stacked(bs.view(), lanes,
+                                              paths))
+                evals.append((fin_groups, paths, pend))
+            for fin_groups, paths, pend in evals:   # round barrier
+                costs = pend.get()
+                # the deadline/idle finishing math is shared per
+                # (t_max, idle) subgroup — one vectorized pass each,
+                # row-identical to per-task evaluation
+                off = 0
+                for sub in fin_groups.values():
+                    n_sub = sum(len(fresh[t.uid]) for t in sub)
+                    batch = sub[0].problem.finish_costs(
+                        paths[off:off + n_sub],
+                        {ck: val[off:off + n_sub]
+                         for ck, val in costs.items()})
+                    soff = 0
+                    for t in sub:
+                        n = len(fresh[t.uid])
+                        t.take_rows({ck: val[soff:soff + n]
+                                     for ck, val in batch.items()})
+                        soff += n
+                    off += n_sub
+            for t in todo:
+                if len(fresh[t.uid]) == 0:
+                    t.take_rows(None)
+            todo = [t for t in todo if t.request is not None
+                    and t.request.kind in ("eval", "eval_batch")]
+
+    def retire() -> None:
+        # -- bookkeeping phase: completions, cuts, admission
+        for sw in sweeps:
+            still = []
+            for task in sw.active:
+                if task.request is None:
+                    sw.finish(task)
+                    caches.evict_members(task.uid)
+                    live_uids.discard(task.uid)
+                else:
+                    still.append(task)
+            sw.active = still
+
+    try:
+        with span(spans.SWEEP):
             admit_all()
+            while any(sw.active for sw in sweeps):
+                active = [t for sw in sweeps for t in sw.active]
+                fleet["stacked_rounds"] += 1
+                with span(spans.ROUND, tasks=len(active)):
+                    groups = group(active)
+                    with span(spans.ROUND_DISPATCH):
+                        inflight = dispatch(groups)
+                    with span(spans.ROUND_BARRIER):
+                        raw = collect(inflight)
+                    with span(spans.ROUND_EVAL):
+                        evaluate(active, raw)
+                    with span(spans.ROUND_ADMIT):
+                        retire()
+                        admit_all()
     finally:
         # eviction normally happens per finished task; an aborted
         # run evicts its still-live tasks' member stacks here so a

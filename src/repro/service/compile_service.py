@@ -39,6 +39,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.core import orchestrator as _orchestrator
+from repro.core import spans
 from repro.core.backend import get_backend
 from repro.core.context import CompilationContext
 from repro.core.goals import (
@@ -337,7 +338,8 @@ class CompileService:
         worker's cross-process writes are batched per admitted batch,
         never interleaved into the solve loop.
         """
-        with self.store.deferred_publication():
+        with self.store.deferred_publication(), \
+                spans.span(spans.COMPILE_MANY, n=len(requests)):
             return self._compile_many(requests,
                                       stack_networks=stack_networks)
 
@@ -352,9 +354,10 @@ class CompileService:
         for i, req in enumerate(requests):
             cfg = req.cfg or OrchestratorConfig()
             goal = req.resolve_goal()
-            ctx = self.context_for(req.specs, cfg=cfg,
-                                   network=req.network,
-                                   cost_model=req.cost_model)
+            with spans.span(spans.CONTEXT):
+                ctx = self.context_for(req.specs, cfg=cfg,
+                                       network=req.network,
+                                       cost_model=req.cost_model)
             ctxs[i] = ctx
             if isinstance(goal, ParetoFront):
                 deadlines = goal.resolve_deadlines(
@@ -432,12 +435,13 @@ class CompileService:
                 [unit["job"].sweep for unit in units], backend=backend,
                 caches=self.store.stack_caches)
             for unit in units:
-                sched = unit["job"].emit(fleet)
-                value = sched if sched is not None \
-                    else _orchestrator.infeasible_result(unit["goal"],
-                                                         unit["ctx"])
-                if self.use_schedule_cache:
-                    self.store.put_schedule(unit["key"], value)
+                with spans.span(spans.EMIT):
+                    sched = unit["job"].emit(fleet)
+                    value = sched if sched is not None \
+                        else _orchestrator.infeasible_result(
+                            unit["goal"], unit["ctx"])
+                    if self.use_schedule_cache:
+                        self.store.put_schedule(unit["key"], value)
                 unit["value"] = value
                 write(unit, value)
         # resolve in-batch duplicates (shared solve, rebound label)
