@@ -31,8 +31,8 @@ call on that subset's own padded tensors; the round-based rail-subset
 scheduler (:func:`repro.core.rails.select_rails_stacked`) relies on
 exactly this to stay provably selection-identical to the sequential
 sweep.  On jax the stacked kernels are ``vmap(lax.scan)`` programs and
-the lane count is padded to a power-of-two bucket so rounds of
-different widths reuse one compilation.
+the lane count is padded to its power-of-four rung (:func:`lane_rung`)
+so rounds of different widths reuse a few compilations.
 
 Backend selection: ``get_backend(None)`` honours the ``PFDNN_BACKEND``
 environment variable (``numpy`` | ``jax``), defaulting to numpy, so the
@@ -339,12 +339,24 @@ def _as_stacked(padded: PaddedArrays) -> StackedArrays:
 
 
 def lane_bucket(n: int) -> int:
-    """Round a lane count up to a power of two (≥ 1) so jitted stacked
-    kernels keep stable shapes as rounds shrink and grow."""
+    """Round a column or row count up to a power of two (≥ 1) so jitted
+    stacked kernels keep stable shapes as rounds shrink and grow."""
     b = 1
     while b < n:
         b *= 2
     return b
+
+
+def lane_rung(n: int) -> int:
+    """Lane padding of an ``n``-lane jitted dispatch: the smallest power
+    of four ≥ ``n`` (1, 4, 16, ...).  A dispatch computes at most four
+    times its lanes, and a lane program needs one compilation per rung
+    (powers of two would waste at most half, for half again as many
+    programs)."""
+    r = 1
+    while r < n:
+        r *= 4
+    return r
 
 
 # ------------------------------------------------- persistent lane stores
@@ -371,11 +383,11 @@ class BucketStack:
         self._cap = 8
         self.slot: dict = {}
         self._lock = make_lock("backend.bucket._lock")
-        # monotonic lane-padding floor for the jitted stacked kernels:
-        # remembering the bucket's high-water mark means recompiles
-        # happen only on genuine growth, never when a fleet's live lane
-        # count shrinks and then regrows across rounds
-        self.lane_pad = 1
+        # the highest lane rung (lane_rung) any jitted dispatch on this
+        # store has needed: the backend builds the store's lane programs
+        # at every rung up to it (JaxBackend._close_rungs), so a round
+        # whose live lanes shrink and regrow never compiles
+        self.top_rung = 1
         # backend-owned per-bucket scratch (device lane mirrors, host
         # member-gather memos) — dies with the stack, so clearing or
         # trimming the caches frees device buffers too
@@ -437,16 +449,6 @@ class BucketStack:
                 valid=self._valid[b], t_trans=self._t_trans[b],
                 e_trans=self._e_trans[b], switch=self._switch[b],
                 sizes=tuple(int(s) for s in self._sizes[b]))
-
-    def lane_pad_for(self, n: int) -> int:
-        """Lane-padding bucket for an ``n``-lane call against this
-        store: ``lane_bucket(n)``, rounded up to the store's historical
-        maximum so kernel shapes only ever grow (see ``__init__``)."""
-        with self._lock:
-            b = lane_bucket(n)
-            if b > self.lane_pad:
-                self.lane_pad = b
-            return self.lane_pad
 
     def view(self) -> StackedArrays:
         # lock-free fast path: _view is only ever replaced whole (add
@@ -588,7 +590,7 @@ class _LaneMirror:
     synced by :meth:`JaxBackend._mirror`; lives in the stack's scratch
     dict so it is dropped together with the host lanes)."""
 
-    __slots__ = ("arrays", "cap", "n")
+    __slots__ = ("arrays", "cap", "n", "families")
 
     def __init__(self):
         # (t_op, e_op, valid, t_trans, e_trans, switch) device arrays
@@ -596,6 +598,10 @@ class _LaneMirror:
         self.arrays: tuple | None = None
         self.cap = 0
         self.n = 0
+        # lane programs dispatched on these arrays, keyed (jitted
+        # program, padded column count): (first weight rows, rungs
+        # built) — see JaxBackend._close_rungs
+        self.families: dict = {}
 
 
 # ----------------------------------------------------------- numpy
@@ -939,10 +945,12 @@ class JaxBackend:
         # device-lane path (benches and transfer-counting tests read
         # this; increments are stats-only, so no lock); lane_slots
         # counts the (lane, column) cells the DP and k-best dispatches
-        # computed, lane_slots_used those that were not padding
+        # computed, lane_slots_used those that were not padding;
+        # lane_rung_builds counts the discarded calls that build a
+        # store's lane programs at the rungs it has not dispatched
         self.io_stats = {"h2d_lane_uploads": 0, "h2d_lane_bytes": 0,
                          "kernel_dispatches": 0, "lane_slots": 0,
-                         "lane_slots_used": 0}
+                         "lane_slots_used": 0, "lane_rung_builds": 0}
         # On CPU hosts the jitted programs only pay for themselves on
         # reduction-heavy work: gather-bound path evaluation and tiny
         # DP slabs are dominated by dispatch + host↔device copies, so
@@ -1134,18 +1142,14 @@ class JaxBackend:
         return self._host.path_costs(problem, paths)
 
     # -- stacked variants ---------------------------------------------
-    # Lane counts are padded to a power-of-two bucket (repeating lane 0)
-    # so every round width of the subset-stacked sweep reuses one
-    # compiled program; the pad lanes are dropped before returning.
+    # Lane counts are padded to their power-of-four rung (repeating lane
+    # 0) so the round widths of the subset-stacked sweep share a few
+    # compiled programs; the pad lanes are dropped before returning.
 
     @staticmethod
     def _pad_lanes(stacked: StackedArrays) -> tuple[StackedArrays, int]:
         B = stacked.n_lanes
-        # honour the owning BucketStack's monotonic padding floor when
-        # the round scheduler provided one (stamped at stack creation),
-        # so shrink-then-regrow round widths reuse one compilation
-        Bp = max(lane_bucket(B),
-                 stacked.dev_cache.get("lane_pad_hint", 1))
+        Bp = lane_rung(B)
         if Bp == B:
             return stacked, B
         if "lanes_pad" in stacked.dev_cache:    # memoized per instance
@@ -1334,6 +1338,8 @@ class JaxBackend:
             with self._x64(), spans.span(spans.LANES_UPLOAD,
                                          lanes=store.n - m.n):
                 if m.cap != cap:
+                    # new shapes: every program is built anew
+                    m.families = {}
                     old = m.arrays or (None,) * len(host)
                     grown = []
                     for arr, h in zip(old, host):
@@ -1443,20 +1449,51 @@ class JaxBackend:
         fn = jax.jit(impl)
         return self._lanes_jits.setdefault(key, fn)
 
-    def _pad_lane_group(self, store: BucketStack, lanes: Sequence[int],
-                        rows: list[np.ndarray]
+    @staticmethod
+    def _pad_lane_group(lanes: Sequence[int], rows: list[np.ndarray]
                         ) -> tuple[np.ndarray, list[np.ndarray], int]:
-        """Pad a lane group (and its per-lane weight rows) to the
-        store's monotonic lane bucket, repeating lane 0 / row 0 — the
-        results of pad lanes are computed and discarded."""
+        """Pad a lane group (and its per-lane weight rows) to its lane
+        rung, repeating lane 0 / row 0 — the results of pad lanes are
+        computed and discarded."""
         B = len(lanes)
-        Bp = store.lane_pad_for(B)
+        Bp = lane_rung(B)
         idx = np.asarray(list(lanes) + [lanes[0]] * (Bp - B),
                          dtype=np.int64)
         if Bp != B:
             rows = [np.concatenate(
                 [r, np.repeat(r[:1], Bp - B, axis=0)]) for r in rows]
         return idx, rows, B
+
+    def _close_rungs(self, store: BucketStack, m: _LaneMirror, fn,
+                     rows: list[np.ndarray]) -> None:
+        """Build ``fn``'s and the store's other lane programs at every
+        rung up to the store's top rung, before ``fn`` dispatches the
+        padded ``rows``: a program family (the jitted program on this
+        mirror at one padded column count) seen for the first time is
+        built at the rungs below, and a rise of the top rung builds
+        every family at the new rungs.  So a dispatch at any rung up to
+        the top hits the jit's in-memory cache, and set-up that reaches
+        a store's top rung leaves no compile to the rounds after it.
+        Each build is one discarded call on lane 0 with the family's
+        first weight row repeated."""
+        rung = len(rows[0])
+        family = (fn, rows[0].shape[1])
+        with store._lock:
+            store.top_rung = top = max(store.top_rung, rung)
+            m.families.setdefault(
+                family, ([r[:1] for r in rows], set()))[1].add(rung)
+            # (top is a power of four)
+            rungs = [4 ** i for i in range(top.bit_length() // 2 + 1)]
+            todo = [(f, r0, g) for (f, _), (r0, built) in m.families.items()
+                    for g in rungs if g not in built]
+            for _, built in m.families.values():
+                built.update(rungs)
+        jnp = self._jax.numpy
+        for f, r0, g in todo:
+            with self._x64():
+                f(*m.arrays[:5], jnp.asarray(np.zeros(g, dtype=np.int64)),
+                  *(jnp.asarray(np.repeat(r, g, axis=0)) for r in r0))
+            self.io_stats["lane_rung_builds"] += 1
 
     def _count_dispatch(self, slots: int, used: int) -> None:
         """Tally one DP or k-best dispatch of ``slots`` (lane, column)
@@ -1482,10 +1519,11 @@ class JaxBackend:
                 self._host_member_stack(store, lanes), w_e, w_t)
             return PendingResult.ready(out) if defer else out
         m = self._mirror(store)
-        idx, (w, t), B = self._pad_lane_group(store, lanes, [w_e, w_t])
+        idx, (w, t), B = self._pad_lane_group(lanes, [w_e, w_t])
         (w, t), K = self._pad_cols([w, t])
         jnp = self._jax.numpy
         fn = self._lanes_fn("dp")
+        self._close_rungs(store, m, fn, [w, t])
         with self._x64():
             dev = fn(*m.arrays[:5], jnp.asarray(idx),
                      jnp.asarray(w), jnp.asarray(t))
@@ -1507,10 +1545,11 @@ class JaxBackend:
                 self._host_member_stack(store, lanes), mus, k)
             return PendingResult.ready(out) if defer else out
         m = self._mirror(store)
-        idx, (mr,), B = self._pad_lane_group(store, lanes, [mus])
+        idx, (mr,), B = self._pad_lane_group(lanes, [mus])
         (mr,), K = self._pad_cols([mr])
         jnp = self._jax.numpy
         fn = self._lanes_fn("kbest", k)
+        self._close_rungs(store, m, fn, [mr])
         with self._x64():
             dev_p, dev_c = fn(*m.arrays[:5], jnp.asarray(idx),
                               jnp.asarray(mr))

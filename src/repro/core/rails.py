@@ -619,14 +619,7 @@ def run_stacked_sweeps(
         # reduction kernels never read them (cost gathers go through
         # the persistent BucketStack views instead)
         key = (tasks[0].bucket,) + tuple(t.uid for t in tasks)
-        stack = caches.member_stack(key, [t.padded for t in tasks])
-        # stamp the owning store's monotonic lane-padding floor so the
-        # jitted stacked kernels only ever recompile on genuine growth
-        # (never when the live lane count shrinks and regrows)
-        stack.dev_cache.setdefault(
-            "lane_pad_hint",
-            tasks[0].lane_store.lane_pad_for(len(tasks)))
-        return stack
+        return caches.member_stack(key, [t.padded for t in tasks])
 
     def group(active) -> dict[tuple, list]:
         # -- kernel phase: one stacked call per request-shape group.

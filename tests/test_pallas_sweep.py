@@ -16,8 +16,8 @@ here pins the mode to the numpy backend bit-for-bit:
   - a hypothesis property sweeps random level sets / μ grids;
   - warm sweep rounds move ZERO operand bytes host→device (the
     transfer counters only tick when a lane is first admitted);
-  - lane padding is monotonic per store, so shrink-then-regrow round
-    widths never recompile.
+  - a lane group pads to its power-of-four rung, and the padded tail
+    lanes are dropped.
 """
 
 import json
@@ -40,6 +40,7 @@ from repro.core.backend import (
     PendingResult,
     StackCaches,
     build_padded,
+    lane_rung,
     repad,
     stack_padded,
 )
@@ -178,17 +179,18 @@ def test_pallas_ties_break_first_occurrence(rng):
             kbest_rows_to_lists(op[b], oc[b])
 
 
-def test_pallas_padded_tail_lanes_are_dropped(rng):
-    """Lane counts off the power-of-two bucket (and widened by the
-    monotonic pad hint) are padded with repeats of lane 0; the result
-    rows of the real lanes must be untouched by the padding."""
+@pytest.mark.parametrize("n_lanes", [3, 5])     # rungs 4 and 16
+def test_pallas_padded_tail_lanes_are_dropped(rng, n_lanes):
+    """Lane counts off their power-of-four rung are padded with repeats
+    of lane 0; the result rows of the real lanes must be untouched by
+    the padding."""
     pk = get_backend(PALLAS)
     ref = get_backend("numpy")
     problems = [random_problem(rng, n_layers=3, n_states=4)
-                for _ in range(3)]                  # 3 lanes → pad to 4+
+                for _ in range(n_lanes)]
     stack = _stack_from(problems)
-    stack.dev_cache["lane_pad_hint"] = 8            # force a wide pad
-    w = rng.random((3, 2))
+    assert pk._pad_lanes(stack)[0].n_lanes == lane_rung(n_lanes)
+    w = rng.random((n_lanes, 2))
     np.testing.assert_array_equal(
         pk.dp_multi_stacked(stack, w, w + 1.0),
         ref.dp_multi_stacked(stack, w, w + 1.0))
@@ -370,12 +372,10 @@ def test_warm_sweep_rounds_upload_nothing(monkeypatch, rng):
             assert got[0]["path"] == ref[0]["path"]
 
 
-def test_lane_pad_is_monotonic_per_store():
-    store = BucketStack(2, 3)
-    assert store.lane_pad_for(3) == 4
-    assert store.lane_pad_for(2) == 4      # never shrinks
-    assert store.lane_pad_for(5) == 8
-    assert store.lane_pad_for(1) == 8
+@pytest.mark.parametrize("n,rung", [(1, 1), (2, 4), (4, 4), (5, 16),
+                                    (16, 16)])
+def test_lane_rung(n, rung):
+    assert lane_rung(n) == rung
 
 
 def test_pending_result_defers_and_memoizes():

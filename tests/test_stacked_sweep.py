@@ -35,7 +35,13 @@ from repro.core import (
     solve_lambda_dp,
 )
 from repro.core.lambda_dp import kbest_rows_to_lists
-from repro.core.backend import build_padded, repad, stack_padded
+from repro.core.backend import (
+    BucketStack,
+    JaxBackend,
+    build_padded,
+    repad,
+    stack_padded,
+)
 from repro.core.problem import IdleModel, ScheduleProblem, StateCost
 from repro.core.rails import all_rail_subsets
 from repro.hw.dvfs import TransitionModel
@@ -179,6 +185,125 @@ def test_jax_jitted_kernels_match_numpy(monkeypatch):
     for b in range(2):
         assert kbest_rows_to_lists(jp[b], jc[b]) == \
             kbest_rows_to_lists(rp[b], rc[b])
+
+
+def _lane_store(rng, n_lanes: int, n_layers: int, n_states: int
+                ) -> BucketStack:
+    store = BucketStack(n_layers, n_states)
+    for i in range(n_lanes):
+        store.add(("lane", i), build_padded(random_problem(
+            rng, n_layers=n_layers, n_states=n_states)))
+    return store
+
+
+@pytest.mark.skipif("jax" not in BACKENDS, reason="jax not installed")
+@pytest.mark.parametrize("n_lanes", [1, 3, 5])      # rungs 1, 4 and 16
+def test_lanes_at_every_rung_match_numpy(n_lanes):
+    """The lanes API pads a group to its rung and drops the pad lanes:
+    the DP's paths and the k-best frontier are numpy's, bit for bit.
+    The slabs clear the CPU routing floors, so even one lane runs the
+    jitted programs."""
+    bk = get_backend("jax")
+    ref = get_backend("numpy")
+    rng = np.random.default_rng(n_lanes)
+    L, S, K, k = 8, 64, 16, 8
+    assert K * L * S * S >= bk._JIT_MIN_WORK
+    assert K * k * L * S * S >= bk._KBEST_JIT_MIN_WORK
+    store = _lane_store(rng, n_lanes, L, S)
+    lanes = list(range(n_lanes))
+    members = bk._host_member_stack(store, lanes)
+    w_e = np.ones((n_lanes, K))
+    w_t = rng.uniform(0.0, 0.5, (n_lanes, K))
+    mus = rng.uniform(0.0, 0.5, (n_lanes, K))
+    before = bk.io_stats["kernel_dispatches"]
+    np.testing.assert_array_equal(
+        bk.dp_multi_lanes(store, lanes, w_e, w_t),
+        ref.dp_multi_stacked(members, w_e, w_t))
+    got_p, got_c = bk.kbest_multi_lanes(store, lanes, mus, k)
+    exp_p, exp_c = ref.kbest_multi_stacked(members, mus, k)
+    np.testing.assert_array_equal(got_c, exp_c)
+    np.testing.assert_array_equal(got_p, exp_p)
+    assert bk.io_stats["kernel_dispatches"] == before + 2
+
+
+@pytest.mark.skipif("jax" not in BACKENDS, reason="jax not installed")
+def test_lane_programs_are_built_up_to_the_top_rung():
+    """A store's lane programs are built at every rung up to the highest
+    any of its dispatches needed: once it has reached rung 16, groups of
+    1, 2 and 5 lanes compile nothing and compute rung·Kp slots each."""
+    bk = JaxBackend()
+    bk._cpu = False                # the jitted programs at any size
+    rng = np.random.default_rng(7)
+    store = _lane_store(rng, 16, 4, 4)
+    dp, kbest = bk._lanes_fn("dp"), bk._lanes_fn("kbest", 3)
+
+    def dispatch(n: int) -> dict:
+        before = dict(bk.io_stats)
+        lanes = list(range(n))
+        bk.dp_multi_lanes(store, lanes, np.ones((n, 3)),
+                          rng.random((n, 3)))
+        bk.kbest_multi_lanes(store, lanes, rng.random((n, 3)), 3)
+        return {key: bk.io_stats[key] - before[key] for key in before}
+
+    # both programs first dispatch at rung 1, with nothing below it
+    assert dispatch(1)["lane_rung_builds"] == 0
+    # the DP raises the top rung to 16: the DP is built at rung 4, the
+    # k-best (already built at rung 1) at rungs 4 and 16
+    assert dispatch(16)["lane_rung_builds"] == 3
+    assert dp._cache_size() == kbest._cache_size() == 3
+    for n, rung in ((1, 1), (2, 4), (5, 16)):
+        delta = dispatch(n)
+        assert delta["lane_rung_builds"] == 0
+        assert dp._cache_size() == kbest._cache_size() == 3
+        assert delta["kernel_dispatches"] == 2
+        assert delta["lane_slots"] == 2 * rung * 4          # Kp = 4
+        assert delta["lane_slots_used"] == 2 * n * 3
+
+
+@pytest.mark.skipif("jax" not in BACKENDS, reason="jax not installed")
+def test_concurrent_lane_dispatches_build_each_rung_once():
+    """Threads sharing one store (as compilations of one service do)
+    dispatch groups of every width: each program ends up built at rungs
+    1, 4 and 16 only, and every result is numpy's."""
+    import sys
+    import threading
+
+    bk = JaxBackend()
+    bk._cpu = False
+    ref = get_backend("numpy")
+    store = _lane_store(np.random.default_rng(11), 16, 4, 4)
+    errors: list = []
+
+    def work(seed: int) -> None:
+        rng = np.random.default_rng(seed)
+        try:
+            for _ in range(6):
+                lanes = list(rng.choice(16, int(rng.integers(1, 17)),
+                                        replace=False))
+                w = rng.random((len(lanes), 3))
+                np.testing.assert_array_equal(
+                    bk.dp_multi_lanes(store, lanes, np.ones_like(w), w),
+                    ref.dp_multi_stacked(
+                        bk._host_member_stack(store, lanes),
+                        np.ones_like(w), w))
+        except Exception as exc:     # reported by the main thread
+            errors.append(exc)
+
+    saved = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(saved)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert store.top_rung == 16
+    assert bk._lanes_fn("dp")._cache_size() == 3
 
 
 # ------------------------------- stacked sweep vs sequential selection
