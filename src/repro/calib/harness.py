@@ -298,14 +298,17 @@ def solver_kernel_walls(backend: str | None = None, *,
 
     rng = np.random.default_rng(np.random.SeedSequence([seed]))
     L, S, K = int(n_layers), int(s_pad), int(k_weights)
+    # every boundary its own transition block
+    states = np.tile(np.arange(S, dtype=np.int32), (L - 1, 1))
     padded = PaddedArrays(
         t_op=rng.uniform(1e-5, 1e-3, (L, S)),
         e_op=rng.uniform(1e-7, 1e-5, (L, S)),
         valid=np.ones((L, S), dtype=bool),
-        t_trans=rng.uniform(0.0, 1e-5, (L - 1, S, S)),
-        e_trans=rng.uniform(0.0, 1e-7, (L - 1, S, S)),
-        switch=np.zeros((L - 1, S, S), dtype=np.int64),
-        sizes=(S,) * L)
+        t_blk=rng.uniform(0.0, 1e-5, (L - 1, S, S)),
+        e_blk=rng.uniform(0.0, 1e-7, (L - 1, S, S)),
+        sw_blk=np.zeros((L - 1, S, S), dtype=np.int64),
+        block_of=np.arange(L - 1, dtype=np.int32), rsel=states,
+        csel=states, sizes=(S,) * L)
     w_e = np.linspace(0.2, 1.0, K)
     w_t = 1.0 - w_e
     be = get_backend(backend)

@@ -49,6 +49,14 @@ refused with :class:`PallasDeviceUnsupported`: the TPU compiler rejects
 these kernels, so on the chip the stacked sweep runs as the plain jax
 backend's ``lax.scan`` programs.
 
+Transitions are stored compactly (:class:`PaddedArrays`): each
+distinct ``[S, S]`` transition block once, and per layer boundary the
+block it reads and its states' rows and columns in it — a network's
+boundaries share a handful of blocks (one per pair of adjacent voltage
+tables), however deep it is.  The kernels read each boundary's block at
+its step, so results are bit-identical to reading a dense per-boundary
+tensor: the numbers read are the same numbers.
+
 The jax backend is also **device-resident**: every :class:`BucketStack`
 gets a device mirror of its lane tensors, synced incrementally — each
 lane is uploaded ONCE when first seen, capacity growth copies on
@@ -66,7 +74,8 @@ host→device traffic and dispatch counts are tallied in
 tests.
 
 Padding convention (:class:`PaddedArrays`): op costs are padded with 0
-and carry a ``valid`` mask; kernels mask *after* applying the λ weights
+and carry a ``valid`` mask, and pad states read finite block entries;
+kernels mask *after* applying the λ weights
 (``inf`` only ever enters post-weighting), so negative idle-priced μ
 never produces ``inf · μ`` NaNs.  Valid states occupy the index prefix
 of every padded axis, which keeps ``argmin`` first-occurrence tie
@@ -84,6 +93,7 @@ import os
 import pathlib
 import sys
 import threading
+import weakref
 
 from repro.analysis.lockcheck import make_lock
 from typing import Sequence
@@ -134,18 +144,29 @@ def _pallas_mode_from_env() -> str | None:
 
 @dataclasses.dataclass(frozen=True)
 class PaddedArrays:
-    """Dense per-layer tensors of a :class:`ScheduleProblem`.
+    """Padded per-layer tensors of a :class:`ScheduleProblem`.
 
     ``S`` is the padded state count (power-of-two bucket ≥ the widest
     layer); valid states sit at indices ``0..sizes[i]-1``.
+
+    Transitions are kept once per distinct block: ``t_blk`` / ``e_blk``
+    / ``sw_blk`` hold ``NB`` blocks of ``SB × SB``, and boundary ``i``
+    (layer ``i`` → ``i+1``) reads block ``block_of[i]``, its state ``a``
+    at row ``rsel[i, a]`` and the next layer's state ``b`` at column
+    ``csel[i, b]`` (:meth:`edges`).  Boundaries whose layers share
+    voltage tables share one block, so NB is a handful however deep the
+    network; a pruned layer's kept states are its rows of the block.
     """
 
     t_op: np.ndarray        # [L, S] float64, padded with 0
     e_op: np.ndarray        # [L, S] float64, padded with 0
     valid: np.ndarray       # [L, S] bool
-    t_trans: np.ndarray     # [L-1, S, S] float64, padded with 0
-    e_trans: np.ndarray     # [L-1, S, S] float64, padded with 0
-    switch: np.ndarray      # [L-1, S, S] int64 rail-switch flags
+    t_blk: np.ndarray       # [NB, SB, SB] float64 transition latencies
+    e_blk: np.ndarray       # [NB, SB, SB] float64 transition energies
+    sw_blk: np.ndarray      # [NB, SB, SB] int64 rail-switch flags
+    block_of: np.ndarray    # [L-1] int32 block of each boundary
+    rsel: np.ndarray        # [L-1, S] int32 block row of layer i's state
+    csel: np.ndarray        # [L-1, S] int32 block column of i+1's state
     sizes: tuple[int, ...]  # true per-layer state counts
     # per-instance scratch for backend device copies (jax converts the
     # tensors once per instance instead of once per kernel call); the
@@ -160,6 +181,21 @@ class PaddedArrays:
     @property
     def s_pad(self) -> int:
         return self.t_op.shape[1]
+
+    @property
+    def n_blocks(self) -> int:
+        return self.t_blk.shape[0]
+
+    @property
+    def block_size(self) -> int:
+        return self.t_blk.shape[1]
+
+    def edges(self, i: int) -> tuple[np.ndarray, np.ndarray]:
+        """``[S, S]`` (T_trans, E_trans) of boundary ``i`` (pad rows and
+        columns read block entries that the kernels mask or slice)."""
+        ix = (self.block_of[i], self.rsel[i][:, None],
+              self.csel[i][None, :])
+        return self.t_blk[ix], self.e_blk[ix]
 
 
 def pad_bucket(n: int) -> int:
@@ -178,11 +214,17 @@ def pad_bucket(n: int) -> int:
 def build_padded(problem) -> PaddedArrays:
     """Materialize a problem's padded tensors (see module docstring).
 
-    Pad slots of the op tensors are 0 with ``valid`` False; pad slots
-    of the transition tensors carry no contract at all — every kernel
-    either slices them away or masks them through the inf node costs,
-    so the master-backed fast path below may leave arbitrary (finite)
-    master values there.
+    Pad slots of the op tensors are 0 with ``valid`` False; pad states
+    read row / column 0 of their block, finite values that every kernel
+    either slices away or masks through the inf node costs.
+
+    Boundaries are grouped by the matrices they slice: on master-backed
+    problems (the rail sweep's) the context's shared master transition
+    matrices, which it keys by the two layers' voltage-table content;
+    otherwise each boundary's own matrices, by content.  A group's
+    block is its matrices restricted to the union of the states its
+    boundaries use, so every boundary reads the same numbers it would
+    read from its own ``[S_i, S_{i+1}]`` slice.
     """
     L = problem.n_layers
     sizes = problem.sizes
@@ -195,45 +237,63 @@ def build_padded(problem) -> PaddedArrays:
         t_op[i, :sizes[i]] = t
         e_op[i, :sizes[i]] = e
         valid[i, :sizes[i]] = True
-    if L > 1 and problem._trans_src is not None \
-            and not problem._trans_cache:
-        srcs = [problem._trans_src(i) for i in range(L - 1)]
-        if all(s[0] is srcs[0][0] for s in srcs[1:]):
-            # every pair shares ONE master matrix (the common case —
-            # most adjacent layers have identical voltage tables):
-            # gather all L-1 padded slabs in three fancy-index shots
-            # instead of 3·(L-1) per-pair slices.  Pad slots replicate
-            # master row/col 0 — finite garbage, never read (above).
-            mt, me, msw = srcs[0]
-            rows = np.zeros((L - 1, S), dtype=np.int64)
-            cols = np.zeros((L - 1, S), dtype=np.int64)
-            for i in range(L - 1):
-                rows[i, :sizes[i]] = problem._trans_sel[i]
-                cols[i, :sizes[i + 1]] = problem._trans_sel[i + 1]
-            ri = rows[:, :, None]
-            ci = cols[:, None, :]
-            return PaddedArrays(
-                t_op=t_op, e_op=e_op, valid=valid,
-                t_trans=mt[ri, ci], e_trans=me[ri, ci],
-                switch=msw[ri, ci], sizes=sizes)
-    t_trans = np.zeros((max(L - 1, 0), S, S))
-    e_trans = np.zeros((max(L - 1, 0), S, S))
-    switch = np.zeros((max(L - 1, 0), S, S), dtype=np.int64)
+    # key -> (block index, source matrices, rows, cols of its boundaries)
+    groups: dict = {}
+    bounds = []
     for i in range(L - 1):
-        tt, et = problem.transition_arrays(i)
-        sw = problem.switch_arrays(i)
-        t_trans[i, :sizes[i], :sizes[i + 1]] = tt
-        e_trans[i, :sizes[i], :sizes[i + 1]] = et
-        switch[i, :sizes[i], :sizes[i + 1]] = sw
-    return PaddedArrays(t_op=t_op, e_op=e_op, valid=valid,
-                        t_trans=t_trans, e_trans=e_trans, switch=switch,
-                        sizes=sizes)
+        if problem._trans_src is not None:
+            src = problem._trans_src(i)
+            rows, cols = problem._trans_sel[i], problem._trans_sel[i + 1]
+            key = tuple(id(m) for m in src)
+        else:
+            src = problem._ensure_trans(i)
+            rows, cols = np.arange(sizes[i]), np.arange(sizes[i + 1])
+            key = (src[0].shape,) + tuple(m.tobytes() for m in src)
+        g = groups.setdefault(key, (len(groups), src, [], []))
+        g[2].append(rows)
+        g[3].append(cols)
+        bounds.append((g[0], rows, cols))
+    blocks = [(np.unique(np.concatenate(rows)),
+               np.unique(np.concatenate(cols)), src)
+              for _, src, rows, cols in groups.values()]
+    NB = len(blocks)
+    SB = pad_bucket(max([1] + [max(len(r), len(c)) for r, c, _ in blocks]))
+    t_blk = np.zeros((NB, SB, SB))
+    e_blk = np.zeros((NB, SB, SB))
+    sw_blk = np.zeros((NB, SB, SB), dtype=np.int64)
+    for g, (r, c, (tt, et, sw)) in enumerate(blocks):
+        ix = np.ix_(r, c)
+        t_blk[g, :len(r), :len(c)] = tt[ix]
+        e_blk[g, :len(r), :len(c)] = et[ix]
+        sw_blk[g, :len(r), :len(c)] = sw[ix]
+    block_of = np.zeros(max(L - 1, 0), dtype=np.int32)
+    rsel = np.zeros((max(L - 1, 0), S), dtype=np.int32)
+    csel = np.zeros((max(L - 1, 0), S), dtype=np.int32)
+    for i, (g, rows, cols) in enumerate(bounds):
+        block_of[i] = g
+        rsel[i, :len(rows)] = np.searchsorted(blocks[g][0], rows)
+        csel[i, :len(cols)] = np.searchsorted(blocks[g][1], cols)
+    return PaddedArrays(t_op=t_op, e_op=e_op, valid=valid, t_blk=t_blk,
+                        e_blk=e_blk, sw_blk=sw_blk, block_of=block_of,
+                        rsel=rsel, csel=csel, sizes=sizes)
+
+
+def _widen_blocks(blk: np.ndarray, nb: int, sb: int) -> np.ndarray:
+    """``blk [..., NB, SB, SB]`` zero-padded to ``nb`` blocks of
+    ``sb × sb`` (pad blocks and entries are never indexed)."""
+    n, s = blk.shape[-3], blk.shape[-1]
+    if (n, s) == (nb, sb):
+        return blk
+    out = np.zeros(blk.shape[:-3] + (nb, sb, sb), dtype=blk.dtype)
+    out[..., :n, :s, :s] = blk
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
 class StackedArrays:
     """Padded tensors of B same-bucket problems stacked along a new
-    leading *lane* axis (see :func:`stack_padded`).
+    leading *lane* axis (see :func:`stack_padded`), each field of
+    :class:`PaddedArrays` with a leading ``B``.
 
     Lanes are independent: every stacked kernel applied to lane ``b``
     produces bit-identical results to the non-stacked kernel on the
@@ -243,9 +303,12 @@ class StackedArrays:
     t_op: np.ndarray        # [B, L, S]
     e_op: np.ndarray        # [B, L, S]
     valid: np.ndarray       # [B, L, S] bool
-    t_trans: np.ndarray     # [B, L-1, S, S]
-    e_trans: np.ndarray     # [B, L-1, S, S]
-    switch: np.ndarray      # [B, L-1, S, S] int64
+    t_blk: np.ndarray       # [B, NB, SB, SB]
+    e_blk: np.ndarray       # [B, NB, SB, SB]
+    sw_blk: np.ndarray      # [B, NB, SB, SB] int64
+    block_of: np.ndarray    # [B, L-1] int32
+    rsel: np.ndarray        # [B, L-1, S] int32
+    csel: np.ndarray        # [B, L-1, S] int32
     max_sizes: tuple[int, ...]   # per-layer max valid count over lanes
     # per-instance scratch for backend device copies / lane repads (see
     # PaddedArrays.dev_cache) — safe because the tensors are immutable
@@ -264,6 +327,44 @@ class StackedArrays:
     def s_pad(self) -> int:
         return self.t_op.shape[2]
 
+    def edge_index(self, lanes, lt, a, b) -> tuple:
+        """Block coordinates of the transitions from state ``a`` of
+        layer ``lt`` to state ``b`` of layer ``lt + 1`` on lane
+        ``lanes`` (index arrays that broadcast together): index
+        ``t_blk`` / ``e_blk`` / ``sw_blk`` with it."""
+        return (lanes, self.block_of[lanes, lt], self.rsel[lanes, lt, a],
+                self.csel[lanes, lt, b])
+
+    def edges(self, i: int, sp: int, sn: int
+              ) -> tuple[np.ndarray, np.ndarray]:
+        """``[B, sp, sn]`` (T_trans, E_trans) of boundary ``i``, its
+        first ``sp`` rows and ``sn`` columns."""
+        ix = self.edge_index(np.arange(self.n_lanes)[:, None, None], i,
+                             np.arange(sp)[None, :, None],
+                             np.arange(sn)[None, None, :])
+        return self.t_blk[ix], self.e_blk[ix]
+
+
+def dense_edges(blk, block_of, rsel, csel):
+    """``[B, L-1, S, S]`` dense view of stacked blocks (numpy or jax
+    arrays) — for the Pallas interpret kernels only, which run on the
+    CPU and take dense transition tensors."""
+    B = blk.shape[0]
+    return blk[np.arange(B)[:, None, None, None],
+               block_of[:, :, None, None], rsel[:, :, :, None],
+               csel[:, :, None, :]]
+
+
+def _take_lanes(stacked: StackedArrays, idx: np.ndarray) -> StackedArrays:
+    """Lanes ``idx`` of a stack (repeats allowed), gathered on the host."""
+    valid = stacked.valid[idx]
+    return StackedArrays(
+        t_op=stacked.t_op[idx], e_op=stacked.e_op[idx], valid=valid,
+        t_blk=stacked.t_blk[idx], e_blk=stacked.e_blk[idx],
+        sw_blk=stacked.sw_blk[idx], block_of=stacked.block_of[idx],
+        rsel=stacked.rsel[idx], csel=stacked.csel[idx],
+        max_sizes=tuple(int(m) for m in valid.sum(axis=2).max(axis=0)))
+
 
 def bucket_key(padded: PaddedArrays) -> tuple[int, int]:
     """The shape class a problem's padded tensors belong to — problems
@@ -281,50 +382,41 @@ def repad(padded: PaddedArrays, s_pad: int) -> PaddedArrays:
         return padded
     if s_pad < S:
         raise ValueError(f"cannot shrink pad bucket {S} -> {s_pad}")
-    t_op = np.zeros((L, s_pad))
-    e_op = np.zeros((L, s_pad))
-    valid = np.zeros((L, s_pad), dtype=bool)
-    t_op[:, :S] = padded.t_op
-    e_op[:, :S] = padded.e_op
-    valid[:, :S] = padded.valid
-    t_trans = np.zeros((max(L - 1, 0), s_pad, s_pad))
-    e_trans = np.zeros((max(L - 1, 0), s_pad, s_pad))
-    switch = np.zeros((max(L - 1, 0), s_pad, s_pad), dtype=np.int64)
-    t_trans[:, :S, :S] = padded.t_trans
-    e_trans[:, :S, :S] = padded.e_trans
-    switch[:, :S, :S] = padded.switch
-    return PaddedArrays(t_op=t_op, e_op=e_op, valid=valid,
-                        t_trans=t_trans, e_trans=e_trans, switch=switch,
-                        sizes=padded.sizes)
+
+    def wide(arr):
+        out = np.zeros(arr.shape[:-1] + (s_pad,), dtype=arr.dtype)
+        out[..., :S] = arr
+        return out
+
+    return dataclasses.replace(
+        padded, t_op=wide(padded.t_op), e_op=wide(padded.e_op),
+        valid=wide(padded.valid), rsel=wide(padded.rsel),
+        csel=wide(padded.csel), dev_cache={})
 
 
-def stack_padded(padded_list: Sequence[PaddedArrays], *,
-                 with_switch: bool = True) -> StackedArrays:
-    """Stack same-bucket padded tensors along a new leading lane axis.
-
-    ``with_switch=False`` substitutes a zero-strided dummy for the
-    rail-switch tensor — the DP and k-best kernels never read it, and
-    skipping the [B, L-1, S, S] int64 copy matters when the sweep
-    restacks a bucket every round.
-    """
+def stack_padded(padded_list: Sequence[PaddedArrays]) -> StackedArrays:
+    """Stack same-bucket padded tensors along a new leading lane axis
+    (blocks widened to the widest member's count and size)."""
     keys = {bucket_key(p) for p in padded_list}
     if len(keys) != 1:
         raise ValueError(
             f"cannot stack mixed padded buckets {sorted(keys)}")
     sizes = np.array([p.sizes for p in padded_list])
-    if with_switch:
-        switch = np.stack([p.switch for p in padded_list])
-    else:
-        switch = np.broadcast_to(
-            np.zeros((), dtype=np.int64),
-            (len(padded_list),) + padded_list[0].switch.shape)
+    nb = max(p.n_blocks for p in padded_list)
+    sb = max(p.block_size for p in padded_list)
+
+    def blocks(name):
+        return np.stack([_widen_blocks(getattr(p, name), nb, sb)
+                         for p in padded_list])
+
+    def lanes(name):
+        return np.stack([getattr(p, name) for p in padded_list])
+
     return StackedArrays(
-        t_op=np.stack([p.t_op for p in padded_list]),
-        e_op=np.stack([p.e_op for p in padded_list]),
-        valid=np.stack([p.valid for p in padded_list]),
-        t_trans=np.stack([p.t_trans for p in padded_list]),
-        e_trans=np.stack([p.e_trans for p in padded_list]),
-        switch=switch,
+        t_op=lanes("t_op"), e_op=lanes("e_op"), valid=lanes("valid"),
+        t_blk=blocks("t_blk"), e_blk=blocks("e_blk"),
+        sw_blk=blocks("sw_blk"), block_of=lanes("block_of"),
+        rsel=lanes("rsel"), csel=lanes("csel"),
         max_sizes=tuple(int(m) for m in sizes.max(axis=0)),
     )
 
@@ -333,9 +425,10 @@ def _as_stacked(padded: PaddedArrays) -> StackedArrays:
     """View one problem as a single-lane stack (kernel reuse)."""
     return StackedArrays(
         t_op=padded.t_op[None], e_op=padded.e_op[None],
-        valid=padded.valid[None], t_trans=padded.t_trans[None],
-        e_trans=padded.e_trans[None], switch=padded.switch[None],
-        max_sizes=padded.sizes)
+        valid=padded.valid[None], t_blk=padded.t_blk[None],
+        e_blk=padded.e_blk[None], sw_blk=padded.sw_blk[None],
+        block_of=padded.block_of[None], rsel=padded.rsel[None],
+        csel=padded.csel[None], max_sizes=padded.sizes)
 
 
 def lane_bucket(n: int) -> int:
@@ -361,12 +454,25 @@ def lane_rung(n: int) -> int:
 
 # ------------------------------------------------- persistent lane stores
 
+# a lane's arrays in a BucketStack, each [cap, ...]; the block arrays are
+# [cap, NB, SB, SB] at the store's block capacity
+_LANE_ARRAYS = ("_t_op", "_e_op", "_valid", "_t_blk", "_e_blk", "_sw_blk",
+                "_block_of", "_rsel", "_csel", "_sizes", "_nblk")
+_BLOCK_ARRAYS = ("_t_blk", "_e_blk", "_sw_blk")
+
+
 class BucketStack:
     """Persistent lane store of one padded bucket: every problem admitted
     to the bucket copies its padded tensors in ONCE, under a *lane key*;
     gather-based stacked calls (path cost evaluation, refinement move
     scoring) then read zero-copy views with global lane indices instead
     of restacking members every round.
+
+    Each lane keeps its distinct transition blocks once
+    (:class:`PaddedArrays`).  The store's block capacity ``n_blocks``
+    (a power of two) and ``block_size`` are the same for all its lanes,
+    so device shapes stay stable; they grow, like the lane capacity,
+    when a lane needs more.
 
     Lane keys are caller-chosen hashables.  Content-derived keys (e.g.
     ``(network content key, rails, gating)``) make the store reusable
@@ -392,25 +498,36 @@ class BucketStack:
         # member-gather memos) — dies with the stack, so clearing or
         # trimming the caches frees device buffers too
         self.scratch: dict = {}
-        L, S = n_layers, s_pad
-        self._t_op = np.zeros((self._cap, L, S))
-        self._e_op = np.zeros((self._cap, L, S))
-        self._valid = np.zeros((self._cap, L, S), dtype=bool)
-        self._t_trans = np.zeros((self._cap, max(L - 1, 0), S, S))
-        self._e_trans = np.zeros((self._cap, max(L - 1, 0), S, S))
-        self._switch = np.zeros((self._cap, max(L - 1, 0), S, S),
-                                dtype=np.int64)
-        self._sizes = np.zeros((self._cap, L), dtype=np.int64)
+        self.n_blocks = 1
+        self.block_size = 4
+        L, S, cap = n_layers, s_pad, self._cap
+        Lb = max(L - 1, 0)
+        self._t_op = np.zeros((cap, L, S))
+        self._e_op = np.zeros((cap, L, S))
+        self._valid = np.zeros((cap, L, S), dtype=bool)
+        self._t_blk = np.zeros((cap, 1, 4, 4))
+        self._e_blk = np.zeros((cap, 1, 4, 4))
+        self._sw_blk = np.zeros((cap, 1, 4, 4), dtype=np.int64)
+        self._block_of = np.zeros((cap, Lb), dtype=np.int32)
+        self._rsel = np.zeros((cap, Lb, S), dtype=np.int32)
+        self._csel = np.zeros((cap, Lb, S), dtype=np.int32)
+        self._sizes = np.zeros((cap, L), dtype=np.int64)
+        # each lane's own block count (the rest are capacity padding)
+        self._nblk = np.zeros(cap, dtype=np.int64)
         self._view: StackedArrays | None = None
 
     def _grow(self) -> None:
         self._cap *= 2
-        for name in ("_t_op", "_e_op", "_valid", "_t_trans",
-                     "_e_trans", "_switch", "_sizes"):
+        for name in _LANE_ARRAYS:
             old = getattr(self, name)
             new = np.zeros((self._cap,) + old.shape[1:], dtype=old.dtype)
             new[:old.shape[0]] = old
             setattr(self, name, new)
+
+    def _grow_blocks(self, nb: int, sb: int) -> None:
+        self.n_blocks, self.block_size = nb, sb
+        for name in _BLOCK_ARRAYS:
+            setattr(self, name, _widen_blocks(getattr(self, name), nb, sb))
 
     def add(self, key, padded: PaddedArrays) -> int:
         """Admit ``padded`` under ``key`` (idempotent: an already
@@ -420,14 +537,22 @@ class BucketStack:
                 return self.slot[key]
             if self.n == self._cap:
                 self._grow()
+            nb, sb = padded.n_blocks, padded.block_size
+            if nb > self.n_blocks or sb > self.block_size:
+                self._grow_blocks(max(self.n_blocks, lane_bucket(nb)),
+                                  max(self.block_size, sb))
             b = self.n
             self._t_op[b] = padded.t_op
             self._e_op[b] = padded.e_op
             self._valid[b] = padded.valid
-            self._t_trans[b] = padded.t_trans
-            self._e_trans[b] = padded.e_trans
-            self._switch[b] = padded.switch
+            self._t_blk[b, :nb, :sb, :sb] = padded.t_blk
+            self._e_blk[b, :nb, :sb, :sb] = padded.e_blk
+            self._sw_blk[b, :nb, :sb, :sb] = padded.sw_blk
+            self._block_of[b] = padded.block_of
+            self._rsel[b] = padded.rsel
+            self._csel[b] = padded.csel
             self._sizes[b] = padded.sizes
+            self._nblk[b] = int(padded.block_of.max(initial=-1)) + 1
             self.slot[key] = b
             self.n += 1
             self._view = None
@@ -446,8 +571,10 @@ class BucketStack:
                 return None
             return PaddedArrays(
                 t_op=self._t_op[b], e_op=self._e_op[b],
-                valid=self._valid[b], t_trans=self._t_trans[b],
-                e_trans=self._e_trans[b], switch=self._switch[b],
+                valid=self._valid[b], t_blk=self._t_blk[b],
+                e_blk=self._e_blk[b], sw_blk=self._sw_blk[b],
+                block_of=self._block_of[b], rsel=self._rsel[b],
+                csel=self._csel[b],
                 sizes=tuple(int(s) for s in self._sizes[b]))
 
     def view(self) -> StackedArrays:
@@ -462,8 +589,10 @@ class BucketStack:
                 n = self.n
                 self._view = StackedArrays(
                     t_op=self._t_op[:n], e_op=self._e_op[:n],
-                    valid=self._valid[:n], t_trans=self._t_trans[:n],
-                    e_trans=self._e_trans[:n], switch=self._switch[:n],
+                    valid=self._valid[:n], t_blk=self._t_blk[:n],
+                    e_blk=self._e_blk[:n], sw_blk=self._sw_blk[:n],
+                    block_of=self._block_of[:n], rsel=self._rsel[:n],
+                    csel=self._csel[:n],
                     max_sizes=tuple(int(m)
                                     for m in self._sizes[:n].max(axis=0)))
             return self._view
@@ -524,14 +653,14 @@ class StackCaches:
 
     def member_stack(self, key: tuple,
                      padded_list: Sequence[PaddedArrays]) -> StackedArrays:
-        """Round member stack for the reduction kernels (switch tensors
-        skipped — those kernels never read them).  Keys carry run-unique
-        task uids, so concurrent schedulers never collide; the lock only
-        orders the dict mutations against concurrent eviction."""
+        """Round member stack for the reduction kernels.  Keys carry
+        run-unique task uids, so concurrent schedulers never collide;
+        the lock only orders the dict mutations against concurrent
+        eviction."""
         hit = self.member_stacks.get(key)   # GIL-atomic read
         if hit is not None:
             return hit
-        stack = stack_padded(padded_list, with_switch=False)
+        stack = stack_padded(padded_list)
         with self._lock:
             return self.member_stacks.setdefault(key, stack)
 
@@ -562,12 +691,15 @@ class PendingResult:
     collects the first result, overlapping Python round bookkeeping
     with device execution."""
 
-    __slots__ = ("_fn", "_value", "_done")
+    __slots__ = ("_fn", "_value", "_done", "dispatch")
 
-    def __init__(self, fn):
+    def __init__(self, fn, dispatch: tuple | None = None):
         self._fn = fn
         self._done = False
         self._value = None
+        # the device lane dispatch behind the handle, (kind, k, L,
+        # S_pad, NB, SB, rung, Kp); None for a host computation
+        self.dispatch = dispatch
 
     @classmethod
     def ready(cls, value) -> "PendingResult":
@@ -590,14 +722,19 @@ class _LaneMirror:
     synced by :meth:`JaxBackend._mirror`; lives in the stack's scratch
     dict so it is dropped together with the host lanes)."""
 
-    __slots__ = ("arrays", "cap", "n", "families")
+    __slots__ = ("arrays", "shape", "n", "families", "nbytes",
+                 "__weakref__")
 
     def __init__(self):
-        # (t_op, e_op, valid, t_trans, e_trans, switch) device arrays
-        # at the mirrored capacity; rows [0, n) are resident lanes
+        # device arrays of JaxBackend._LANE_NAMES at the mirrored
+        # (lane capacity, block capacity, block size); rows [0, n) are
+        # resident lanes
         self.arrays: tuple | None = None
-        self.cap = 0
+        self.shape = (0, 0, 0)
         self.n = 0
+        # [device bytes of arrays], shared with the backend's finalizer
+        # that takes them off io_stats["lane_mirror_bytes"]
+        self.nbytes = [0]
         # lane programs dispatched on these arrays, keyed (jitted
         # program, padded column count): (first weight rows, rungs
         # built) — see JaxBackend._close_rungs
@@ -644,9 +781,10 @@ class NumpyBackend:
         rows_k = np.arange(K)[:, None]
         cols_s = np.arange(S)[None, :]
         for i in range(1, L):
+            tt, et = padded.edges(i - 1)
             # in-place accumulation: same adds, fewer [K, S, S] temps
-            tot = w_e3 * padded.e_trans[i - 1]
-            tot += w_t3 * padded.t_trans[i - 1]
+            tot = w_e3 * et
+            tot += w_t3 * tt
             tot += cost[:, :, None]                           # [K, Sp, Sn]
             parents[i - 1] = np.argmin(tot, axis=1)           # [K, Sn]
             # gather the min from the argmin result — same bits as a
@@ -687,10 +825,11 @@ class NumpyBackend:
         qi3 = np.arange(K)[None, :, None]
         for i in range(1, L):
             sp, sn = sz[i - 1], sz[i]
+            tt, et = stacked.edges(i - 1, sp, sn)
             # accumulate the weighted edge + prefix cost in place —
             # same adds, two fewer [B, K, sp, sn] temporaries
-            tot = we4 * stacked.e_trans[:, None, i - 1, :sp, :sn]
-            tot += wt4 * stacked.t_trans[:, None, i - 1, :sp, :sn]
+            tot = we4 * et[:, None]
+            tot += wt4 * tt[:, None]
             tot += cost[:, :, :, None]                    # [B, K, sp, sn]
             parents.append(np.argmin(tot, axis=2))
             # gather the min from the argmin result — same bits as a
@@ -743,12 +882,12 @@ class NumpyBackend:
             return {"t_op": t_op, "e_op": e_op, "t_trans": zero,
                     "e_trans": zero.copy(),
                     "n_switch": np.zeros(t_op.shape, dtype=np.int64)}
-        lt = np.arange(L - 1)[None, :]
-        a, b = paths[:, :-1], paths[:, 1:]
+        ix = stacked.edge_index(ln, np.arange(L - 1)[None, :],
+                                paths[:, :-1], paths[:, 1:])
         return {"t_op": t_op, "e_op": e_op,
-                "t_trans": stacked.t_trans[ln, lt, a, b].sum(axis=1),
-                "e_trans": stacked.e_trans[ln, lt, a, b].sum(axis=1),
-                "n_switch": stacked.switch[ln, lt, a, b].sum(axis=1)}
+                "t_trans": stacked.t_blk[ix].sum(axis=1),
+                "e_trans": stacked.e_blk[ix].sum(axis=1),
+                "n_switch": stacked.sw_blk[ix].sum(axis=1)}
 
     # above this state count the dense padded tensors stop paying for
     # themselves (the per-layer loop gathers from the ragged arrays
@@ -773,22 +912,9 @@ class NumpyBackend:
         if problem._padded is not None or (
                 paths.shape[0] >= self._PAD_EVAL_MIN_PATHS
                 and max(problem.sizes) <= self._PAD_EVAL_MAX_STATES):
-            padded = problem.padded_arrays()
-            L = padded.n_layers
-            li = np.arange(L)[None, :]
-            t_op = padded.t_op[li, paths].sum(axis=1)
-            e_op = padded.e_op[li, paths].sum(axis=1)
-            if L == 1:
-                zero = np.zeros_like(t_op)
-                return {"t_op": t_op, "e_op": e_op, "t_trans": zero,
-                        "e_trans": zero.copy(),
-                        "n_switch": np.zeros(t_op.shape, dtype=np.int64)}
-            lt = np.arange(L - 1)[None, :]
-            a, b = paths[:, :-1], paths[:, 1:]
-            return {"t_op": t_op, "e_op": e_op,
-                    "t_trans": padded.t_trans[lt, a, b].sum(axis=1),
-                    "e_trans": padded.e_trans[lt, a, b].sum(axis=1),
-                    "n_switch": padded.switch[lt, a, b].sum(axis=1)}
+            return self.path_costs_stacked(
+                _as_stacked(problem.padded_arrays()),
+                np.zeros(paths.shape[0], dtype=np.int64), paths)
 
         p = paths
         n = p.shape[0]
@@ -875,8 +1001,8 @@ def _kbest_stacked_numpy(stacked: StackedArrays, mus: np.ndarray,
     qi4 = np.arange(K)[None, :, None, None]
     for i in range(1, L):
         sp, sn = sz[i - 1], sz[i]
-        edge = (stacked.e_trans[:, None, i - 1, :sp, :sn]
-                + mu4 * stacked.t_trans[:, None, i - 1, :sp, :sn])
+        tt, et = stacked.edges(i - 1, sp, sn)
+        edge = et[:, None] + mu4 * tt[:, None]
         cand = (costs[:, :, :, :, None]
                 + edge[:, :, :, None, :]).reshape(B, K, sp * k, sn)
         order = _topk_stable(cand, k)
@@ -947,10 +1073,14 @@ class JaxBackend:
         # counts the (lane, column) cells the DP and k-best dispatches
         # computed, lane_slots_used those that were not padding;
         # lane_rung_builds counts the discarded calls that build a
-        # store's lane programs at the rungs it has not dispatched
+        # store's lane programs at the rungs it has not dispatched;
+        # lane_blocks counts the distinct transition blocks of the lanes
+        # uploaded, lane_mirror_bytes (a gauge) the device bytes of all
+        # live lane mirrors
         self.io_stats = {"h2d_lane_uploads": 0, "h2d_lane_bytes": 0,
                          "kernel_dispatches": 0, "lane_slots": 0,
-                         "lane_slots_used": 0, "lane_rung_builds": 0}
+                         "lane_slots_used": 0, "lane_rung_builds": 0,
+                         "lane_blocks": 0, "lane_mirror_bytes": 0}
         # On CPU hosts the jitted programs only pay for themselves on
         # reduction-heavy work: gather-bound path evaluation and tiny
         # DP slabs are dominated by dispatch + host↔device copies, so
@@ -974,8 +1104,9 @@ class JaxBackend:
     def _x64(self):
         return self._jax.enable_x64(True)
 
-    _DP_NAMES = ("t_op", "e_op", "valid", "t_trans", "e_trans")
-    _COST_NAMES = ("t_op", "e_op", "t_trans", "e_trans", "switch")
+    # the DP / k-best operands, in the kernels' argument order
+    _DP_NAMES = ("t_op", "e_op", "valid", "t_blk", "e_blk", "block_of",
+                 "rsel", "csel")
 
     def _dev(self, arrs, names: tuple[str, ...]):
         """Device copies of ``arrs``'s tensors, converted once per
@@ -991,7 +1122,20 @@ class JaxBackend:
                                    for n in names)
         return cache[key]
 
-    def _dp_impl(self, t_op, e_op, valid, t_trans, e_trans, w_e, w_t):
+    # The scans work in a boundary's *block columns*: a step reads its
+    # block's rows of the layer's states (whole rows — a gather of
+    # single elements is an order of magnitude slower on a TPU),
+    # reduces over the states for every block column, and then reads
+    # the next layer's states' columns out of the [K, SB] result.  The
+    # numbers compared for state b are those of column csel[b], so the
+    # paths are those of a [S, S] slice.
+
+    def _columns(self, x, cs):
+        """``x[..., cs]``: the next layer's states' block columns."""
+        return self._jax.numpy.take(x, cs, axis=-1, mode="clip")
+
+    def _dp_impl(self, t_op, e_op, valid, t_blk, e_blk, block_of, rsel,
+                 csel, w_e, w_t):
         jnp = self._jax.numpy
         lax = self._jax.lax
         L = t_op.shape[0]
@@ -1007,14 +1151,16 @@ class JaxBackend:
         w_t3 = w_t[:, None, None]
 
         def step(cost, xs):
-            et_i, tt_i, node_i = xs
+            bo, rs, cs, node_i = xs
+            et_i = e_blk[bo][rs]                              # [S, SB]
+            tt_i = t_blk[bo][rs]
             tot = cost[:, :, None] + (w_e3 * et_i + w_t3 * tt_i)
-            parent = jnp.argmin(tot, axis=1)                  # [K, Sn]
-            cost = jnp.min(tot, axis=1) + node_i
-            return cost, parent
+            parent = self._columns(jnp.argmin(tot, axis=1), cs)
+            cost = self._columns(jnp.min(tot, axis=1), cs) + node_i
+            return cost, parent                               # [K, S]
 
         cost, parents = lax.scan(step, node[0],
-                                 (e_trans, t_trans, node[1:]))
+                                 (block_of, rsel, csel, node[1:]))
 
         s_final = jnp.argmin(cost, axis=1)                    # [K]
         rows = jnp.arange(K)
@@ -1049,8 +1195,8 @@ class JaxBackend:
             taken = taken | (pos == jnp.expand_dims(pick, 1))
         return jnp.stack(picks, axis=1)
 
-    def _kbest_impl(self, t_op, e_op, valid, t_trans, e_trans, mus, *,
-                    k: int):
+    def _kbest_impl(self, t_op, e_op, valid, t_blk, e_blk, block_of, rsel,
+                    csel, mus, *, k: int):
         """Single-problem multi-μ k-best frontier as a ``lax.scan``
         program — the jax twin of the numpy stacked kernel's per-lane
         operations (:meth:`_smallest_k` selects in numpy's stable
@@ -1066,17 +1212,21 @@ class JaxBackend:
         mu3 = mus[:, None, None]
 
         def step(costs, xs):
-            tt, et, nd = xs
-            edge = et[None, :, :] + mu3 * tt[None, :, :]     # [K, Sp, Sn]
+            bo, rs, cs, nd = xs
+            tt = t_blk[bo][rs]                              # [S, SB]
+            et = e_blk[bo][rs]
+            edge = et[None, :, :] + mu3 * tt[None, :, :]     # [K, S, SB]
             cand = (costs[:, :, :, None]
-                    + edge[:, :, None, :]).reshape(K, S * k, S)
-            order = self._smallest_k(cand, k)               # [K, k, S]
+                    + edge[:, :, None, :]).reshape(K, S * k, -1)
+            order = self._smallest_k(cand, k)               # [K, k, SB]
             vals = jnp.take_along_axis(cand, order, axis=1)
+            order = self._columns(order, cs)                # [K, k, S]
+            vals = self._columns(vals, cs)
             new_costs = vals.transpose(0, 2, 1) + nd[:, :, None]
             return new_costs, (order // k, order % k)
 
         costs, (ps, pr) = lax.scan(step, costs0,
-                                   (t_trans, e_trans, node[1:]))
+                                   (block_of, rsel, csel, node[1:]))
         flat = costs.reshape(K, S * k)
         order = self._smallest_k(flat, k)                    # [K, k]
         counts = jnp.minimum(k, jnp.isfinite(flat).sum(axis=1))
@@ -1099,9 +1249,8 @@ class JaxBackend:
         if key not in self._kbest_jits:
             jax = self._jax
 
-            def single(t_op, e_op, valid, t_trans, e_trans, mus):
-                return self._kbest_impl(t_op, e_op, valid, t_trans,
-                                        e_trans, mus, k=k)
+            def single(*args):
+                return self._kbest_impl(*args, k=k)
 
             fn = jax.vmap(single) if stacked else single
             self._kbest_jits[key] = jax.jit(fn)
@@ -1155,16 +1304,7 @@ class JaxBackend:
         if "lanes_pad" in stacked.dev_cache:    # memoized per instance
             return stacked.dev_cache["lanes_pad"], B
         idx = np.minimum(np.arange(Bp), B - 1)
-        # a zero-strided switch dummy (stack_padded with_switch=False)
-        # stays a dummy — fancy indexing would materialize the zeros
-        switch = stacked.switch[idx] if stacked.switch.strides[0] else \
-            np.broadcast_to(np.zeros((), dtype=np.int64),
-                            (Bp,) + stacked.switch.shape[1:])
-        padded = StackedArrays(
-            t_op=stacked.t_op[idx], e_op=stacked.e_op[idx],
-            valid=stacked.valid[idx], t_trans=stacked.t_trans[idx],
-            e_trans=stacked.e_trans[idx], switch=switch,
-            max_sizes=stacked.max_sizes)
+        padded = _take_lanes(stacked, idx)
         stacked.dev_cache["lanes_pad"] = padded
         return padded, B
 
@@ -1212,16 +1352,16 @@ class JaxBackend:
             w = np.concatenate([w, np.repeat(w[:1], pad, axis=0)])
             t = np.concatenate([t, np.repeat(t[:1], pad, axis=0)])
         (w, t), K = self._pad_cols([w, t])
-        dev = self._dev(stacked, self._DP_NAMES)
         with self._x64():
             if self.pallas_mode is not None:
                 from repro.kernels.dp_sweep import dp_multi_stacked_pallas
                 paths = dp_multi_stacked_pallas(
-                    *dev, jnp.asarray(w), jnp.asarray(t),
-                    interpret=True)
+                    *self._pallas_dev(stacked)[:5], jnp.asarray(w),
+                    jnp.asarray(t), interpret=True)
             else:
                 paths = self._dp_stacked(
-                    *dev, jnp.asarray(w), jnp.asarray(t))
+                    *self._dev(stacked, self._DP_NAMES),
+                    jnp.asarray(w), jnp.asarray(t))
             return np.asarray(paths, dtype=np.int64)[:B, :K]
 
     def kbest_multi(self, padded: PaddedArrays, mus: np.ndarray,
@@ -1251,19 +1391,35 @@ class JaxBackend:
             m = np.concatenate(
                 [m, np.repeat(m[:1], stacked.n_lanes - B, axis=0)])
         (m,), K = self._pad_cols([m])
-        dev = self._dev(stacked, self._DP_NAMES)
         with self._x64():
             if self.pallas_mode is not None:
                 from repro.kernels.dp_sweep import (
                     kbest_multi_stacked_pallas)
                 paths, counts = kbest_multi_stacked_pallas(
-                    *dev, jnp.asarray(m), k=k,
-                    interpret=True)
+                    *self._pallas_dev(stacked)[:5], jnp.asarray(m),
+                    k=k, interpret=True)
             else:
                 paths, counts = self._kbest_fn(k, stacked=True)(
-                    *dev, jnp.asarray(m))
+                    *self._dev(stacked, self._DP_NAMES), jnp.asarray(m))
             return (np.asarray(paths, dtype=np.int64)[:B, :K],
                     np.asarray(counts, dtype=np.int64)[:B, :K])
+
+    def _pallas_dev(self, stacked: StackedArrays) -> tuple:
+        """Device copies of the Pallas interpret kernels' dense operands
+        ``(t_op, e_op, valid, t_trans, e_trans, switch)``, the
+        transition tensors expanded from the blocks on the host (the
+        kernels run on the CPU only), converted once per instance."""
+        cache = stacked.dev_cache
+        if "pallas" not in cache:
+            ix = (stacked.block_of, stacked.rsel, stacked.csel)
+            host = (stacked.t_op, stacked.e_op, stacked.valid,
+                    dense_edges(stacked.t_blk, *ix),
+                    dense_edges(stacked.e_blk, *ix),
+                    dense_edges(stacked.sw_blk, *ix))
+            with self._x64():
+                cache["pallas"] = tuple(self._jax.numpy.asarray(a)
+                                        for a in host)
+        return cache["pallas"]
 
     @staticmethod
     def _host_sums(comps) -> dict[str, np.ndarray]:
@@ -1286,7 +1442,8 @@ class JaxBackend:
                 np.asarray(lanes, dtype=np.int64), floor=64)
             paths_p, _ = self._pad_rows(
                 np.asarray(paths, dtype=np.int64), floor=64)
-            dev = self._dev(stacked, self._COST_NAMES)
+            dev = self._pallas_dev(stacked)
+            dev = (dev[0], dev[1], dev[3], dev[4], dev[5])
             from repro.kernels.dp_sweep import path_components_pallas
             with self._x64():
                 comps = path_components_pallas(
@@ -1302,65 +1459,79 @@ class JaxBackend:
     # host member stack, so warm rounds upload nothing — only the small
     # weight/μ rows go down and only indices come back.
 
-    # the DP / k-best operands; the switch tensor follows them only for
-    # the Pallas cost gather (the scan path prices paths on the host)
-    _LANE_NAMES = ("_t_op", "_e_op", "_valid", "_t_trans", "_e_trans",
-                   "_switch")
+    # the mirror arrays: the DP / k-best operands in the kernels'
+    # argument order (_DP_NAMES), then the switch blocks, which only the
+    # Pallas cost gather reads (the scan path prices paths on the host)
+    _LANE_NAMES = tuple(f"_{nm}" for nm in _DP_NAMES) + ("_sw_blk",)
+    _N_DP = len(_DP_NAMES)
 
     # Device mirrors are allocated at this capacity floor even while
     # the host store is still small: mirror shape is part of every
     # lane-program jit key, so a mirror that tracked the host's 8 →
     # 16 → 32 → 64 doubling would retrace the whole program family at
-    # each step.  64 lanes of padded operands is a few MB — cheap
+    # each step.  64 lanes of compact operands is a few MB — cheap
     # against four rounds of XLA recompilation.
     _MIRROR_MIN_CAP = 64
 
     def _mirror(self, store: BucketStack) -> _LaneMirror:
         """Device mirror of a lane store, synced incrementally: each
         lane's tensors are uploaded ONCE when first admitted (counted
-        in ``io_stats``), capacity growth re-allocates and copies on
-        device — no host round trip — and warm syncs are a pure
-        bookkeeping check.  The mirror lives in the store's scratch
-        dict, so dropping the stack (``ArtifactStore.clear`` /
-        ``trim_stacks``) frees the device buffers with it."""
+        in ``io_stats``), growth of the lane or block capacity
+        re-allocates and copies on device — no host round trip — and
+        warm syncs are a pure bookkeeping check.  The mirror lives in
+        the store's scratch dict, so dropping the stack
+        (``ArtifactStore.clear`` / ``trim_stacks``) frees the device
+        buffers with it."""
         names = self._LANE_NAMES if self.pallas_mode is not None \
-            else self._LANE_NAMES[:5]
+            else self._LANE_NAMES[:self._N_DP]
         key = ("jax_lanes", len(names))
         with store._lock:
             m = store.scratch.get(key)
             if m is None:
                 m = store.scratch[key] = _LaneMirror()
-            cap = max(self._MIRROR_MIN_CAP, store._cap)
-            if m.n == store.n and m.cap == cap:
+                weakref.finalize(m, _release_mirror, self.io_stats,
+                                 m.nbytes)
+            shape = (max(self._MIRROR_MIN_CAP, store._cap),
+                     store.n_blocks, store.block_size)
+            if m.n == store.n and m.shape == shape:
                 return m
             jnp = self._jax.numpy
             host = [getattr(store, nm) for nm in names]
+            new = slice(m.n, store.n)
+            up_bytes = sum(h[new].nbytes for h in host)
             with self._x64(), spans.span(spans.LANES_UPLOAD,
-                                         lanes=store.n - m.n):
-                if m.cap != cap:
+                                         lanes=store.n - m.n,
+                                         bytes=up_bytes):
+                if m.shape != shape:
                     # new shapes: every program is built anew
                     m.families = {}
                     old = m.arrays or (None,) * len(host)
                     grown = []
                     for arr, h in zip(old, host):
-                        new = jnp.zeros((cap,) + h.shape[1:],
+                        out = jnp.zeros((shape[0],) + h.shape[1:],
                                         dtype=h.dtype)
                         if arr is not None and m.n:
-                            new = new.at[:m.n].set(arr[:m.n])
-                        grown.append(new)
+                            keep = (slice(0, m.n),) + tuple(
+                                slice(0, d) for d in arr.shape[1:])
+                            out = out.at[keep].set(arr[keep])
+                        grown.append(out)
                     m.arrays = tuple(grown)
-                    m.cap = cap
+                    m.shape = shape
+                    nbytes = sum(a.nbytes for a in m.arrays)
+                    self.io_stats["lane_mirror_bytes"] += \
+                        nbytes - m.nbytes[0]
+                    m.nbytes[0] = nbytes
                 if store.n > m.n:
                     # all newly admitted lanes go up as ONE block per
-                    # tensor (6 dispatches total, not 6 per lane) —
+                    # tensor (one dispatch per array, not per lane) —
                     # counters still track per-lane admission
                     m.arrays = tuple(
-                        self._set_block(arr, jnp.asarray(h[m.n:store.n]),
-                                        m.n)
+                        self._set_block(arr, jnp.asarray(h[new]), m.n)
                         for arr, h in zip(m.arrays, host))
                     self.io_stats["h2d_lane_uploads"] += store.n - m.n
-                    self.io_stats["h2d_lane_bytes"] += sum(
-                        h[m.n:store.n].nbytes for h in host)
+                    self.io_stats["h2d_lane_bytes"] += up_bytes
+                    self.io_stats["lane_blocks"] += int(
+                        store._nblk[new].sum())
                 m.n = store.n
             return m
 
@@ -1372,23 +1543,12 @@ class JaxBackend:
         round groups repeat while their tasks live, so warm rounds
         reuse the gather exactly like the old member-stack cache."""
         key = ("hostmember", tuple(lanes))
+        view = store.view()
         with store._lock:
             hit = store.scratch.get(key)
             if hit is not None:
                 return hit
-            idx = np.asarray(lanes, dtype=np.int64)
-            stack = StackedArrays(
-                t_op=store._t_op[idx], e_op=store._e_op[idx],
-                valid=store._valid[idx],
-                t_trans=store._t_trans[idx],
-                e_trans=store._e_trans[idx],
-                # DP / k-best never read the switch tensor — skip the
-                # [B, L-1, S, S] int64 gather (stack_padded idiom)
-                switch=np.broadcast_to(
-                    np.zeros((), dtype=np.int64),
-                    (len(lanes),) + store._switch.shape[1:]),
-                max_sizes=tuple(int(x)
-                                for x in store._sizes[idx].max(axis=0)))
+            stack = _take_lanes(view, np.asarray(lanes, dtype=np.int64))
             memo = [k for k in store.scratch if k[0] == "hostmember"]
             if len(memo) >= 32:
                 del store.scratch[memo[0]]
@@ -1399,50 +1559,65 @@ class JaxBackend:
         """Jitted lane-gather program per (kind, k): the mirror arrays
         go in whole and the lane gather happens ON DEVICE, so the only
         host→device traffic per call is the index/weight rows.  The
-        program is named ``pfdnn_<kind>_lanes`` (one name for every k),
-        so its XLA module reads ``jit_pfdnn_<kind>_lanes`` in a
-        profiler trace."""
+        gather copies each lane's op rows, block indices and its
+        ``[NB, SB, SB]`` blocks; the scan reads each boundary's block
+        at its step.  The program is named ``pfdnn_<kind>_lanes`` (one
+        name for every k), so its XLA module reads
+        ``jit_pfdnn_<kind>_lanes`` in a profiler trace."""
         key = (kind, k)
         fn = self._lanes_jits.get(key)
         if fn is not None:
             return fn
         jax = self._jax
         pallas = self.pallas_mode is not None
+
+        def lanes_of(ops, idx):
+            ops = [a[idx] for a in ops]
+            if pallas:          # dense transitions for the CPU kernels
+                ix = ops[5:8]
+                ops = ops[:3] + [dense_edges(ops[3], *ix),
+                                 dense_edges(ops[4], *ix)]
+            return ops
+
         if kind == "dp":
             if pallas:
                 from repro.kernels.dp_sweep import dp_multi_stacked_pallas
 
-                def impl(t_op, e_op, valid, tt, et, idx, w_e, w_t):
+                def impl(*args):
+                    *ops, idx, w_e, w_t = args
                     return dp_multi_stacked_pallas(
-                        t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], w_e, w_t, interpret=True)
+                        *lanes_of(ops, idx), w_e, w_t, interpret=True)
             else:
-                def impl(t_op, e_op, valid, tt, et, idx, w_e, w_t):
+                def impl(*args):
+                    *ops, idx, w_e, w_t = args
                     return jax.vmap(self._dp_impl)(
-                        t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], w_e, w_t)
+                        *lanes_of(ops, idx), w_e, w_t)
         elif kind == "kbest":
             if pallas:
                 from repro.kernels.dp_sweep import (
                     kbest_multi_stacked_pallas)
 
-                def impl(t_op, e_op, valid, tt, et, idx, mus):
+                def impl(*args):
+                    *ops, idx, mus = args
                     return kbest_multi_stacked_pallas(
-                        t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], mus, k=k, interpret=True)
+                        *lanes_of(ops, idx), mus, k=k, interpret=True)
             else:
-                def impl(t_op, e_op, valid, tt, et, idx, mus):
+                def impl(*args):
+                    *ops, idx, mus = args
                     return jax.vmap(
                         lambda *a: self._kbest_impl(*a, k=k))(
-                        t_op[idx], e_op[idx], valid[idx], tt[idx],
-                        et[idx], mus)
+                        *lanes_of(ops, idx), mus)
         elif kind == "costs" and pallas:
             # (the scan path's path costs are the host's — path_costs)
             from repro.kernels.dp_sweep import path_components_pallas
 
-            def impl(t_op, e_op, tt, et, sw, lanes, paths):
+            def impl(t_op, e_op, valid, t_blk, e_blk, block_of, rsel,
+                     csel, sw_blk, lanes, paths):
+                ix = (block_of, rsel, csel)
                 return path_components_pallas(
-                    lanes, paths, t_op, e_op, tt, et, sw, interpret=True)
+                    lanes, paths, t_op, e_op, dense_edges(t_blk, *ix),
+                    dense_edges(e_blk, *ix), dense_edges(sw_blk, *ix),
+                    interpret=True)
         else:
             raise ValueError(f"unknown lanes kernel {kind!r}")
         impl.__name__ = impl.__qualname__ = f"pfdnn_{kind}_lanes"
@@ -1491,7 +1666,8 @@ class JaxBackend:
         jnp = self._jax.numpy
         for f, r0, g in todo:
             with self._x64():
-                f(*m.arrays[:5], jnp.asarray(np.zeros(g, dtype=np.int64)),
+                f(*m.arrays[:self._N_DP],
+                  jnp.asarray(np.zeros(g, dtype=np.int64)),
                   *(jnp.asarray(np.repeat(r, g, axis=0)) for r in r0))
             self.io_stats["lane_rung_builds"] += 1
 
@@ -1501,6 +1677,14 @@ class JaxBackend:
         self.io_stats["kernel_dispatches"] += 1
         self.io_stats["lane_slots"] += slots
         self.io_stats["lane_slots_used"] += used
+
+    @staticmethod
+    def _dispatch_key(kind: str, k: int, store: BucketStack, m, rung: int,
+                      kp: int) -> tuple:
+        """The shape of one lane dispatch, as ``PendingResult.dispatch``
+        and ``solver_stats["lane_dispatches"]`` name it."""
+        L, S = store._t_op.shape[1:]
+        return (kind, k, L, S, m.shape[1], m.shape[2], rung, kp)
 
     def dp_multi_lanes(self, store: BucketStack, lanes: Sequence[int],
                        w_e: np.ndarray, w_t: np.ndarray, *,
@@ -1525,11 +1709,12 @@ class JaxBackend:
         fn = self._lanes_fn("dp")
         self._close_rungs(store, m, fn, [w, t])
         with self._x64():
-            dev = fn(*m.arrays[:5], jnp.asarray(idx),
+            dev = fn(*m.arrays[:self._N_DP], jnp.asarray(idx),
                      jnp.asarray(w), jnp.asarray(t))
         self._count_dispatch(len(idx) * w.shape[1], B * K)
         pend = PendingResult(
-            lambda: np.asarray(dev, dtype=np.int64)[:B, :K])
+            lambda: np.asarray(dev, dtype=np.int64)[:B, :K],
+            self._dispatch_key("dp", 0, store, m, len(idx), w.shape[1]))
         return pend if defer else pend.get()
 
     def kbest_multi_lanes(self, store: BucketStack,
@@ -1551,12 +1736,14 @@ class JaxBackend:
         fn = self._lanes_fn("kbest", k)
         self._close_rungs(store, m, fn, [mr])
         with self._x64():
-            dev_p, dev_c = fn(*m.arrays[:5], jnp.asarray(idx),
+            dev_p, dev_c = fn(*m.arrays[:self._N_DP], jnp.asarray(idx),
                               jnp.asarray(mr))
         self._count_dispatch(len(idx) * mr.shape[1], B * K)
         pend = PendingResult(lambda: (
             np.asarray(dev_p, dtype=np.int64)[:B, :K],
-            np.asarray(dev_c, dtype=np.int64)[:B, :K]))
+            np.asarray(dev_c, dtype=np.int64)[:B, :K]),
+            self._dispatch_key("kbest", k, store, m, len(idx),
+                               mr.shape[1]))
         return pend if defer else pend.get()
 
     def path_costs_lanes(self, store: BucketStack, lanes: np.ndarray,
@@ -1578,17 +1765,20 @@ class JaxBackend:
         m = self._mirror(store)
         lanes_p, P = self._pad_rows(lanes, floor=64)
         paths_p, _ = self._pad_rows(paths, floor=64)
-        cost_arrs = (m.arrays[0], m.arrays[1], m.arrays[3],
-                     m.arrays[4], m.arrays[5])
         jnp = self._jax.numpy
         fn = self._lanes_fn("costs")
         with self._x64():
-            dev = fn(*cost_arrs, jnp.asarray(lanes_p),
+            dev = fn(*m.arrays, jnp.asarray(lanes_p),
                      jnp.asarray(paths_p))
         self.io_stats["kernel_dispatches"] += 1
         pend = PendingResult(
             lambda: self._host_sums(np.asarray(c)[:P] for c in dev))
         return pend if defer else pend.get()
+
+
+def _release_mirror(io_stats: dict, nbytes: list) -> None:
+    """Finalizer of a lane mirror: its device bytes leave the gauge."""
+    io_stats["lane_mirror_bytes"] -= nbytes[0]
 
 
 # ------------------------------------------------- process placement

@@ -48,6 +48,7 @@ from repro.core.rails import (
     MinLatencySelection,
     StackedSweep,
     all_rail_subsets,
+    dispatch_rows,
     evenly_spaced_rails,
     run_stacked_sweeps,
     select_rails,
@@ -522,6 +523,8 @@ class StackedSweepJob:
         sel_stats = dict(self.sweep.stats)
         sel_stats["stacked_rounds"] = fleet["stacked_rounds"]
         sel_stats["stacked_calls"] = fleet["stacked_calls"]
+        sel_stats["lane_dispatches"] = dispatch_rows(
+            fleet["lane_dispatches"])
         if fleet.get("networks", 1) > 1:
             sel_stats["fleet_networks"] = fleet["networks"]
         sel_stats.update(self.agg)

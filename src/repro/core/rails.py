@@ -10,7 +10,7 @@ selecting the overall best solution" (§3.3).
 from __future__ import annotations
 
 import itertools
-from collections import deque
+from collections import Counter, deque
 from typing import Callable, Iterable, Sequence
 
 import numpy as np
@@ -599,7 +599,8 @@ def run_stacked_sweeps(
     if caches is None:
         caches = StackCaches()
     fleet = {"stacked_rounds": 0, "stacked_calls": 0,
-             "networks": len(sweeps)}
+             "networks": len(sweeps),
+             "lane_dispatches": Counter()}
     # uids of tasks admitted but not yet finished: member stacks are
     # keyed by run-unique uids no later run can hit, so an aborted run
     # (backend error, KeyboardInterrupt) must evict its live tasks'
@@ -695,6 +696,8 @@ def run_stacked_sweeps(
                     pend = PendingResult.ready(move_scores(
                         bs.view(), lanes, pa, t_inf, e_idl,
                         key[2], key[3]))
+            if pend.dispatch is not None:
+                fleet["lane_dispatches"][pend.dispatch] += 1
             inflight.append((key, tasks, pend))
         return inflight
 
@@ -848,7 +851,20 @@ def select_rails_stacked(
     stats = dict(sweep.stats)
     stats["stacked_rounds"] = fleet["stacked_rounds"]
     stats["stacked_calls"] = fleet["stacked_calls"]
+    stats["lane_dispatches"] = dispatch_rows(fleet["lane_dispatches"])
     return best, best_subset, stats
+
+
+#: the fields of a device lane dispatch's shape (PendingResult.dispatch)
+DISPATCH_FIELDS = ("kind", "k", "L", "S_pad", "NB", "SB", "rung", "Kp")
+
+
+def dispatch_rows(counts: dict) -> list[dict]:
+    """``solver_stats["lane_dispatches"]``: a sweep's device lane
+    dispatches, one row per shape (:data:`DISPATCH_FIELDS`) with its
+    count ``n`` — plain JSON, like the rest of the solver stats."""
+    return [dict(zip(DISPATCH_FIELDS, key), n=n)
+            for key, n in sorted(counts.items())]
 
 
 def accepts_param(fn: Callable, name: str) -> bool:

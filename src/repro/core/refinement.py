@@ -54,6 +54,14 @@ def move_deltas(problem: ScheduleProblem, path: list[int], i: int
     return d_t, d_e
 
 
+def _take_last(arr: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """``np.take_along_axis(arr, idx, axis=-1)`` for a 3-D ``arr``, as
+    one flat take (several times faster at move-scoring sizes)."""
+    P, L, n = arr.shape
+    base = np.arange(P * L, dtype=np.int64).reshape(P, L, 1) * n
+    return np.take(arr, base + idx)
+
+
 def move_scores(stacked, lanes: np.ndarray, pa: np.ndarray,
                 t_infer: np.ndarray, e_idle: np.ndarray,
                 t_max: float, idle) -> tuple[np.ndarray, np.ndarray,
@@ -81,16 +89,23 @@ def move_scores(stacked, lanes: np.ndarray, pa: np.ndarray,
     d_t = t_op - stacked.t_op[ln, li, pa][:, :, None]
     d_e = e_op - stacked.e_op[ln, li, pa][:, :, None]
     if n_layers > 1:
-        prev, cur_t = pa[:, :-1], pa[:, 1:]             # inbound, i ≥ 1
-        d_t[:, 1:, :] += stacked.t_trans[ln, lt, prev, :]
-        d_t[:, 1:, :] -= stacked.t_trans[ln, lt, prev, cur_t][:, :, None]
-        d_e[:, 1:, :] += stacked.e_trans[ln, lt, prev, :]
-        d_e[:, 1:, :] -= stacked.e_trans[ln, lt, prev, cur_t][:, :, None]
-        cur_h, nxt = pa[:, :-1], pa[:, 1:]              # outbound, i < L-1
-        d_t[:, :-1, :] += stacked.t_trans[ln, lt, :, nxt]
-        d_t[:, :-1, :] -= stacked.t_trans[ln, lt, cur_h, nxt][:, :, None]
-        d_e[:, :-1, :] += stacked.e_trans[ln, lt, :, nxt]
-        d_e[:, :-1, :] -= stacked.e_trans[ln, lt, cur_h, nxt][:, :, None]
+        # per boundary i of each row: its block's row of the path state
+        # (inbound to layer i+1 from every state) and column of the next
+        # path state (outbound of layer i to it), read whole from the
+        # block and then at every state's row / column, [P, L-1, S]
+        a, b = pa[:, :-1], pa[:, 1:]
+        bo = stacked.block_of[ln, lt]                   # [P, L-1]
+        rs, cs = stacked.rsel[lanes], stacked.csel[lanes]
+        r = _take_last(rs, a[:, :, None])[:, :, 0]
+        c = _take_last(cs, b[:, :, None])[:, :, 0]
+        for d, blk in ((d_t, stacked.t_blk), (d_e, stacked.e_blk)):
+            row = blk[ln, bo, r]                        # [P, L-1, SB]
+            cur = _take_last(row, c[:, :, None])
+            d[:, 1:, :] += _take_last(row, cs)          # inbound, i ≥ 1
+            d[:, 1:, :] -= cur
+            col = blk[ln, bo, :, c]                     # [P, L-1, SB]
+            d[:, :-1, :] += _take_last(col, rs)         # outbound, i < L-1
+            d[:, :-1, :] -= cur
     # padded states are not real moves: ΔT → inf makes them
     # infeasible, which the feasibility mask turns into Δ = inf.
     # From here on everything is computed in place on d_t / d_e — the

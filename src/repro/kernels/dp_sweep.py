@@ -22,6 +22,11 @@ argmin/argsort reduction with the follow-up gather per lane:
     the host with ``np.sum`` so warm results are bit-identical to the
     numpy backend's pairwise summation.
 
+The kernels take dense ``[B, L-1, S, S]`` transition tensors: the
+backend expands its compact lane blocks to them on the host
+(``repro.core.backend.dense_edges``), which is why these kernels are a
+CPU interpret vehicle only.
+
 Bit-identity contract (pinned by tests/test_pallas_sweep.py): the
 layer loops are unrolled over the static L, node costs mask invalid
 states to ``inf`` *after* weighting, and all reductions run over the

@@ -53,3 +53,16 @@ def random_problem(rng: np.random.Generator, *, n_layers: int,
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _collect_module_garbage():
+    """Collect each test module's garbage when it ends.  The workers run
+    many files in one process, and a later file's check of the live
+    device arrays (chipbench's float-precision check reads every array
+    JAX still holds) must not see float32 arrays that an earlier
+    module's serving models left in reference cycles."""
+    yield
+    import gc
+
+    gc.collect()

@@ -305,7 +305,8 @@ def test_scan_lanes_price_paths_on_the_host(monkeypatch, rng):
             jb._host_member_stack(store, lanes), w, w))
     assert jb.io_stats["h2d_lane_bytes"] - base["h2d_lane_bytes"] == sum(
         getattr(store, nm)[:len(lanes)].nbytes
-        for nm in ("_t_op", "_e_op", "_valid", "_t_trans", "_e_trans"))
+        for nm in ("_t_op", "_e_op", "_valid", "_t_blk", "_e_blk",
+                   "_block_of", "_rsel", "_csel"))
     mark = dict(jb.io_stats)
     pl = np.asarray([0, 2, 1, 1], dtype=np.int64)
     pp_ = rng.integers(0, 5, (4, 4)).astype(np.int64)

@@ -164,5 +164,32 @@ def test_lane_programs_have_stable_names(monkeypatch, rng):
     idx = np.zeros(2, dtype=np.int64)
     with bk._x64():
         text = bk._lanes_fn("dp").lower(
-            *m.arrays[:5], idx, np.ones((2, 2)), np.ones((2, 2))).as_text()
+            *m.arrays[:bk._N_DP], idx, np.ones((2, 2)),
+            np.ones((2, 2))).as_text()
     assert "jit_pfdnn_dp_lanes" in text and "jit_impl" not in text
+
+
+def test_lane_upload_span_names_its_lanes_and_bytes(tmp_path, monkeypatch,
+                                                    rng):
+    """A cold mirror sync is one ``pfdnn.lanes.upload`` span whose
+    arguments are the lanes it uploads and their bytes, the same bytes
+    ``io_stats["h2d_lane_bytes"]`` counts."""
+    from jax.profiler import ProfileData
+
+    bk = get_backend("jax")
+    monkeypatch.setattr(bk, "_cpu", False)
+    store = _lane_store(rng)
+    before = bk.io_stats["h2d_lane_bytes"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bk._mirror(store)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    ups = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+           for line in plane.lines for e in line.events
+           if e.name == spans.LANES_UPLOAD]
+    assert ups == [{"lanes": 3,
+                    "bytes": bk.io_stats["h2d_lane_bytes"] - before}]
+    assert ups[0]["bytes"] > 0

@@ -4,9 +4,11 @@ No chip is needed: the TPU compiler compiles for a *described* v5e
 topology (``jax.experimental.topologies``) and refuses there what the
 chip would refuse — unsupported dtypes or gathers, misaligned blocks, a
 program too large for the device.  The shapes are the chip path's real
-ones: the ``(L, S_pad)`` buckets of the largest edge network's
-``BucketStack`` lane stores after a CPU compile at the paper's setting,
-at the lane / λ-batch buckets and mirror capacity the round scheduler
+ones: the ``(L, S_pad)`` buckets, block counts and block sizes of the
+largest edge network's ``BucketStack`` lane stores after a CPU compile
+at the paper's setting, and the five-rail stores of S_pad 256 of the
+deep networks (the ``edge40nm-5rail-deep`` benchmark deployment), at
+the lane / λ-batch buckets and mirror capacity the round scheduler
 uses, in float64 (the numeric contract).
 
 The topology is described inside a fixture, never while the module is
@@ -23,6 +25,7 @@ import pytest
 from conftest import max_rate
 from repro.core import OrchestratorConfig, get_backend
 from repro.core.backend import JaxBackend, PallasDeviceUnsupported
+from repro.models.edge_cnn import edge_network
 
 jax = pytest.importorskip("jax")
 
@@ -59,8 +62,8 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def buckets():
-    """``(L, S_pad)`` of every lane store a CPU compile of the largest
-    edge network fills at the paper's setting."""
+    """``(L, S_pad, NB, SB, cap)`` of every lane store a CPU compile of
+    the largest edge network fills at the paper's setting."""
     from repro.models.edge_cnn import edge_network
     from repro.service import CompileService
 
@@ -71,35 +74,75 @@ def buckets():
                                    backend="numpy"),
             network=NETWORK)
         assert sched is not None
-        sigs = sorted({sig[-2:] for sig in svc.store.stack_caches.buckets})
-    assert sigs and all(L == len(edge_network(NETWORK)) for L, _ in sigs)
+        stores = svc.store.stack_caches.buckets.values()
+        sigs = sorted({_shape(bs) for bs in stores})
+    assert sigs and all(s[0] == len(edge_network(NETWORK)) for s in sigs)
     return sigs
 
 
-def _specs(one_chip, L, S, lead):
+def _shape(bs) -> tuple[int, int, int, int, int]:
+    """``(L, S_pad, NB, SB, mirror capacity)`` of a lane store."""
+    L, S = bs._t_op.shape[1:]
+    return (L, S, bs.n_blocks, bs.block_size,
+            max(JaxBackend._MIRROR_MIN_CAP, bs._cap))
+
+
+@pytest.fixture(scope="module")
+def deep_buckets():
+    """The S_pad 256 lane stores of the deep networks' five-rail
+    subsets (the ``edge40nm-5rail-deep`` deployment), built from the
+    pruned subset problems the sweep admits — no solve needed."""
+    from repro.core.backend import BucketStack, build_padded
+    from repro.core.context import CompilationContext
+    from repro.core.pruning import prune_problem
+    from repro.core.rails import all_rail_subsets
+
+    shapes = []
+    for net in ("mobilevit-xxs", "resnet18"):
+        ctx = CompilationContext(edge_network(net), max_rate(net),
+                                 network=net)
+        stores: dict = {}
+        for rails in all_rail_subsets(ctx.levels, 5):
+            if len(rails) < 5:
+                continue
+            pruned, _ = prune_problem(ctx.problem_for(
+                rails, gating=True, allow_sleep=True,
+                materialize_states=False))
+            padded = build_padded(pruned)
+            stores.setdefault(padded.s_pad, BucketStack(
+                padded.n_layers, padded.s_pad)).add(rails, padded)
+        assert 256 in stores, (net, sorted(stores))
+        shapes.append(_shape(stores[256]))
+    return shapes
+
+
+def _specs(one_chip, L, S, NB, SB, lead):
     """ShapeDtypeStructs of the DP/k-best operand set with leading
-    axis ``lead``: t_op, e_op, valid, t_trans, e_trans."""
+    axis ``lead`` (JaxBackend._DP_NAMES): t_op, e_op, valid, t_blk,
+    e_blk, block_of, rsel, csel."""
     f64 = np.dtype("float64")
+    i32 = np.dtype("int32")
 
     def sds(shape, dtype=f64):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     return [sds((lead, L, S)), sds((lead, L, S)),
             sds((lead, L, S), np.dtype(bool)),
-            sds((lead, L - 1, S, S)), sds((lead, L - 1, S, S))], sds
+            sds((lead, NB, SB, SB)), sds((lead, NB, SB, SB)),
+            sds((lead, L - 1), i32), sds((lead, L - 1, S), i32),
+            sds((lead, L - 1, S), i32)], sds
 
 
-def _program(jb, name, one_chip, L, S):
+def _program(jb, name, one_chip, L, S, NB, SB, cap):
     """(jitted program, argument specs) of one chip-path program."""
-    cap = JaxBackend._MIRROR_MIN_CAP
     i64 = np.dtype("int64")
     if name == "dp_stacked":
-        ops, sds = _specs(one_chip, L, S, LANES)
+        ops, sds = _specs(one_chip, L, S, NB, SB, LANES)
         return jb._dp_stacked, ops + [sds((LANES, LAMBDAS))] * 2
     if name == "kbest_stacked":
-        ops, sds = _specs(one_chip, L, S, LANES)
+        ops, sds = _specs(one_chip, L, S, NB, SB, LANES)
         return jb._kbest_fn(K_BEST, True), ops + [sds((LANES, MUS))]
-    ops, sds = _specs(one_chip, L, S, cap)
+    ops, sds = _specs(one_chip, L, S, NB, SB, cap)
     idx = sds((LANES,), i64)
     if name == "dp_lanes":
         return jb._lanes_fn("dp"), ops + [idx] + [sds((LANES, LAMBDAS))] * 2
@@ -108,8 +151,19 @@ def _program(jb, name, one_chip, L, S):
                                                       sds((LANES, MUS))]
     # lane_upload: a round's newly admitted lanes written into the
     # mirror's largest tensor as one block
-    tt = ops[3]
-    return jb._set_block, [tt, sds((LANES, L - 1, S, S)), sds((), i64)]
+    blk = ops[3]
+    return jb._set_block, [blk, sds((LANES, NB, SB, SB)), sds((), i64)]
+
+
+def _compiles(jb, name, one_chip, shapes):
+    with jax.enable_x64(True):
+        for shape in shapes:
+            fn, args = _program(jb, name, one_chip, *shape)
+            compiled = fn.lower(*args).compile()
+            mem = compiled.memory_analysis()
+            # one program of the sweep stays far inside a 16 GB chip
+            assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+                < 1 << 32, (name, shape)
 
 
 @pytest.mark.parametrize("name", ["dp_stacked", "kbest_stacked",
@@ -120,14 +174,22 @@ def test_sweep_program_compiles_for_v5e(name, one_chip, buckets,
     monkeypatch.delenv("PFDNN_PALLAS", raising=False)
     jb = get_backend("jax")
     assert jb.pallas_mode is None
-    with jax.enable_x64(True):
-        for L, S in buckets:
-            fn, args = _program(jb, name, one_chip, L, S)
-            compiled = fn.lower(*args).compile()
-            mem = compiled.memory_analysis()
-            # one program of the sweep stays far inside a 16 GB chip
-            assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
-                < 1 << 32, (name, L, S)
+    _compiles(jb, name, one_chip, buckets)
+
+
+@pytest.mark.parametrize("name", ["dp_lanes", "kbest_lanes",
+                                  "lane_upload"])
+def test_deep_five_rail_lane_programs_compile_for_v5e(
+        name, one_chip, deep_buckets, monkeypatch):
+    """The lane programs of the deep deployment's S_pad 256 stores
+    (mobilevit-xxs's 150-state weightless layers, resnet18) compile for
+    a v5e on the compact lanes: [NB, SB, SB] blocks per lane, NB a
+    handful, however deep the network."""
+    monkeypatch.delenv("PFDNN_PALLAS", raising=False)
+    jb = get_backend("jax")
+    assert all(NB <= 8 and S == SB == 256
+               for _, S, NB, SB, _ in deep_buckets), deep_buckets
+    _compiles(jb, name, one_chip, deep_buckets)
 
 
 def test_pallas_device_mode_is_refused_everywhere(monkeypatch):
